@@ -10,6 +10,9 @@ import time
 
 
 def main() -> int:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (bench_aggregation, bench_concurrency,
                             bench_control, bench_fit, bench_frameworks,
                             bench_kernels, bench_pipeline, bench_placement,

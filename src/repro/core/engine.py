@@ -151,6 +151,10 @@ def s_bucket(s: int, *, base: int = 8) -> int:
         b *= 2
 
 
+# StepCompileCache counters summed across the round's program caches
+_COUNTERS = ("compiles", "evictions", "hits", "entries", "executables")
+
+
 def _cat_parts(outs, i):
     """Concatenate worker/shard partial-output tuples along the W axis.
     i == 0 is the theta pytree (leafwise concat); 1/2 are the weight/loss
@@ -520,6 +524,8 @@ class FederatedEngine:
         self._mesh_shards = (config.mesh_workers
                              if config.mesh_workers >= 2 else 0)
         self._shard_devices = []
+        self._combine_root = None
+        self._shard_params: dict = {}
         if self._mesh_shards:
             if not strategy.associative:
                 raise ValueError(
@@ -529,7 +535,6 @@ class FederatedEngine:
                     "to combine")
             from repro.launch.mesh import fl_combine_topology
             devs, root = fl_combine_topology(self._mesh_shards)
-            self._combine_root = None
             if len(set(devs)) == 1 and devs[0] == jax.devices()[0]:
                 # Single-device host: every shard resolves to the default
                 # device anyway — leave arrays UNCOMMITTED (device=None) so
@@ -538,11 +543,14 @@ class FederatedEngine:
                 # explicitly committed input changes the lowering key once
                 # params become jit outputs).
                 devs = []
-            elif config.combine_mode == "tree":
-                # Multi-device tree combine: the shard merges run where
-                # their partials live; only the merged O(1) partials ship
-                # to the combine root (§3.3's server side).
+            else:
+                # Multi-device mesh: each worker program runs on its
+                # shard's device, and the combine runs on the root (§3.3's
+                # server side), which also holds the global params between
+                # rounds.  Committing them there from the start keeps
+                # every round's arg shardings equal to round 0's.
                 self._combine_root = root
+                self.params = jax.device_put(init_params, root)
             self._shard_devices = devs
         cache_rows = config.device_cache_batches
         row_bytes = 0
@@ -724,24 +732,24 @@ class FederatedEngine:
         stats = self._step_cache.stats()
         if self._worker_step is not None:
             ws, cs = self._worker_step.stats(), self._combine_step.stats()
-            for k in ("compiles", "evictions", "hits", "entries"):
+            for k in _COUNTERS:
                 stats[k] = stats[k] + ws[k] + cs[k]
             stats["worker_step"] = ws
             stats["combine_step"] = cs
             if self._merge_step is not None:
                 ms = self._merge_step.stats()
-                for k in ("compiles", "evictions", "hits", "entries"):
+                for k in _COUNTERS:
                     stats[k] = stats[k] + ms[k]
                 stats["merge_step"] = ms
             if self._host_node_step is not None:
                 hs = self._host_node_step.stats()
-                for k in ("compiles", "evictions", "hits", "entries"):
+                for k in _COUNTERS:
                     stats[k] = stats[k] + hs[k]
                 stats["host_node_step"] = hs
             if self._compress is not None:
                 es = self._encode_step.stats()
                 ccs = self._compressed_combine_step.stats()
-                for k in ("compiles", "evictions", "hits", "entries"):
+                for k in _COUNTERS:
                     stats[k] = stats[k] + es[k] + ccs[k]
                 stats["encode_step"] = es
                 stats["compressed_combine_step"] = ccs
@@ -756,6 +764,30 @@ class FederatedEngine:
     def control_stats(self) -> dict:
         """Control-plane counters (barrier/drift/concurrency; {} when off)."""
         return self.control.stats() if self.control is not None else {}
+
+    def _to_shard(self, tree, shard: int):
+        """``tree`` committed to ``shard``'s device (unchanged when every
+        shard shares the default device)."""
+        if not self._shard_devices:
+            return tree
+        return jax.device_put(tree, self._shard_devices[shard])
+
+    def _params_on(self, shard: int):
+        """The global params on ``shard``'s device: one copy per shard per
+        round (``_execute_mesh`` resets the map), shared by every worker
+        program and encode step there."""
+        if not self._shard_devices:
+            return self.params
+        if shard not in self._shard_params:
+            self._shard_params[shard] = self._to_shard(self.params, shard)
+        return self._shard_params[shard]
+
+    def _to_root(self, tree):
+        """``tree`` committed to the combine root (unchanged on a
+        single-device host): the one cross-device hop of each partial."""
+        if self._combine_root is None:
+            return tree
+        return jax.device_put(tree, self._combine_root)
 
     def _s_align(self, s_real: int) -> int:
         return s_bucket(s_real, base=self.cfg.s_bucket_base)
@@ -1010,9 +1042,10 @@ class FederatedEngine:
                     mesh_map, loads)
             pack_s = time.perf_counter() - tp0
             with tr.span("prep.h2d", t=t):
-                combine_masks = (jax.device_put(arrays.step_mask),
-                                 jax.device_put(arrays.boundary),
-                                 jax.device_put(arrays.weight))
+                root = self._combine_root
+                combine_masks = (jax.device_put(arrays.step_mask, root),
+                                 jax.device_put(arrays.boundary, root),
+                                 jax.device_put(arrays.weight, root))
             return _PreparedRound(t=t, clients=clients, workers=workers,
                                   assignment=assignment, arrays=arrays,
                                   device=None, pack_s=pack_s,
@@ -1143,6 +1176,9 @@ class FederatedEngine:
         program (bit-identical to the fused step's internal tail)."""
         dispatched = []
         shard_slots: dict[int, int] = {}
+        # One copy of the global params per shard device: each worker
+        # program reads the params on its own chip.
+        self._shard_params = {}
         for wid, tname, shard, dev_arrays, cplan, xs, pred in \
                 prep.worker_programs:
             if dev_arrays is None:
@@ -1154,7 +1190,8 @@ class FederatedEngine:
                 batches = self._device_cache.apply(batches, cplan)
                 shard_slots[shard] = max(shard_slots.get(shard, 0),
                                          cplan.worker_slot + 1)
-            out = self._worker_step(self.params, batches, mask, bnd, wt)
+            out = self._worker_step(self._params_on(shard), batches, mask,
+                                    bnd, wt)
             dispatched.append((wid, tname, shard, xs, pred, out))
         if self._device_cache is not None:
             # Elastic churn can shrink (or empty) a shard's worker set;
@@ -1212,8 +1249,9 @@ class FederatedEngine:
         # on that shard — one shard-merge program per device group — so
         # only O(K) merged partials cross, and the cross-shard combine is
         # the same _reduce_partials tail applied to the [K, 1, ...] stack.
-        # (On a real multi-device mesh the concat implies the shard→combine
-        # gather; the runtime inserts those transfers.)
+        # On a multi-device mesh each partial is committed to the root
+        # before the concat (_to_root): flat mode moves every lane
+        # partial, tree mode one merged partial per shard.
         _cat = _cat_parts
 
         if self._merge_step is not None:
@@ -1232,17 +1270,14 @@ class FederatedEngine:
                 ls_s = _cat(outs, 2)
                 mfn, _ = self._merge_step.lookup(
                     (int(n_s.shape[0]), int(n_s.shape[1])))
-                merged = mfn(th, n_s, ls_s)
-                if self._combine_root is not None:
-                    # the cross-shard hop: one merged partial per shard
-                    merged = jax.device_put(merged, self._combine_root)
-                parts.append(merged)
+                # the cross-shard hop: one merged partial per shard
+                parts.append(self._to_root(mfn(th, n_s, ls_s)))
             theta_wp = _cat(parts, 0)
             n_wp = _cat(parts, 1)
             lane_losses = _cat(parts, 2)
             prep.combine_bytes = len(parts) * self._partial_bytes
         else:
-            outs = [d[5] for d in dispatched]
+            outs = [self._to_root(d[5]) for d in dispatched]
             theta_wp = _cat(outs, 0)
             n_wp = _cat(outs, 1)
             lane_losses = _cat(outs, 2)
@@ -1283,15 +1318,14 @@ class FederatedEngine:
                 (int(n_s.shape[0]), int(n_s.shape[1])))
             merged_th, merged_n, merged_ls = mfn(th, n_s, ls_s)
             theta = jax.tree.map(lambda x: x[0, 0], merged_th)
-            payload, res = efn(self.params, theta,
-                               self._compress.residual(shard))
+            payload, res = efn(self._params_on(shard), theta,
+                               self._to_shard(self._compress.residual(shard),
+                                              shard))
             staged[shard] = res
-            if self._combine_root is not None:
-                # the cross-shard hop: only the compressed payload crosses
-                payload = jax.device_put(payload, self._combine_root)
-            payloads.append(payload)
-            ns.append(merged_n[0, 0])
-            losses.append(merged_ls[0, 0])
+            # the cross-shard hop: only the compressed payload crosses
+            payloads.append(self._to_root(payload))
+            ns.append(self._to_root(merged_n[0, 0]))
+            losses.append(self._to_root(merged_ls[0, 0]))
         payload_stack = jax.tree.map(lambda *xs: jnp.stack(xs), *payloads)
         n_stack = jnp.stack(ns)
         loss_stack = jnp.stack(losses)
@@ -1354,17 +1388,20 @@ class FederatedEngine:
             merged_th, merged_n, merged_ls = mfn(th, n_s, ls_s)
             theta = jax.tree.map(lambda x: x[0, 0], merged_th)
             if self._compress is not None:
-                payload, res = efn(self.params, theta,
-                                   self._compress.residual(shard))
+                params_s = self._params_on(shard)
+                payload, res = efn(params_s, theta, self._to_shard(
+                    self._compress.residual(shard), shard))
                 staged[shard] = res
-                theta = dfn(self.params, payload)
+                theta = dfn(params_s, payload)
             slots[shard] = (theta, merged_n[0, 0], merged_ls[0, 0])
         own = self._host_rank
         host_parts: list = [None] * hm.n_hosts
         for h in range(hm.n_hosts):
             if own is not None and h != own:
                 continue
-            blk = slots[h * hm.block:(h + 1) * hm.block]
+            # a host block reduces on its first shard's device
+            blk = [None if p is None else self._to_shard(p, h * hm.block)
+                   for p in slots[h * hm.block:(h + 1) * hm.block]]
             t0h = time.perf_counter()
             part = HostShardMap.pairwise_reduce(blk, node)
             if part is not None and tr.enabled:
@@ -1384,11 +1421,9 @@ class FederatedEngine:
                 f"round {prep.t}: no live shard partials reached the host "
                 "combine")
         prep.combine_bytes = live * self._partial_bytes
-        if self._combine_root is not None:
-            # the host→root hop: one merged partial per live host
-            host_parts = [None if p is None
-                          else jax.device_put(p, self._combine_root)
-                          for p in host_parts]
+        # the host→root hop: one merged partial per live host
+        host_parts = [None if p is None else self._to_root(p)
+                      for p in host_parts]
         root = HostShardMap.pairwise_reduce(host_parts, node)
         theta_wp = jax.tree.map(lambda x: jnp.asarray(x)[None, None], root[0])
         n_wp = jnp.asarray(root[1])[None, None]
@@ -1805,7 +1840,7 @@ class FederatedEngine:
         if self.ckpt is None or self.ckpt.latest_round() is None:
             return False
         params, rnd, extra = self.ckpt.restore(self.params)
-        self.params = params
+        self.params = self._to_root(params)
         self.round_idx = rnd
         if self._device_cache is not None:
             # Cache state is not checkpointed; entries planned for rounds

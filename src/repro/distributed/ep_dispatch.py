@@ -36,19 +36,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 
-def _shard_map(body, *, mesh, in_specs, out_specs):
-    """jax.shard_map (jax >= 0.6, no replica checks) or the experimental
-    shard_map on older versions (which lacks check_vma and spells the
-    equivalent relaxation check_rep=False)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm
-    return sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False)
-
-
 __all__ = ["make_ep_dispatch"]
 
 
@@ -139,8 +126,8 @@ def make_ep_dispatch(mesh, *, batch_axes=("data",), model_axis="model",
                     aux = jax.lax.pmean(aux, a)
                 return out.reshape(bl, s, D), aux
 
-            fn = _shard_map(
-                body, mesh=mesh,
+            fn = jax.shard_map(
+                body, mesh=mesh, check_vma=False,
                 in_specs=(P(bspec, None, None),            # x: batch sharded
                           P(None, None),                   # router replicated
                           P(model_axis, fsdp_axis, None),  # gate [E, D, F]

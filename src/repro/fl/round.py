@@ -611,5 +611,12 @@ class StepCompileCache:
         return len(self._entries)
 
     def stats(self) -> dict:
+        """Counters.  ``executables`` counts what jit actually compiled
+        behind the live entries: one per entry and device it ran on (the
+        mesh path runs one entry on every shard device), plus any hidden
+        recompile of an entry for a new argument placement — the count a
+        steady round must leave unchanged."""
         return {"compiles": self.compiles, "evictions": self.evictions,
-                "hits": self.hits, "entries": len(self._entries)}
+                "hits": self.hits, "entries": len(self._entries),
+                "executables": sum(fn._cache_size()
+                                   for fn in self._entries.values())}

@@ -1,8 +1,16 @@
 """jit'd public wrappers around the Pallas kernels.
 
 These adapt model-layer layouts to kernel layouts (transpose/pad), pick
-block sizes, and fall back to interpret mode off-TPU so the same call sites
-work in tests (CPU), dry-runs, and on real hardware.
+block sizes the TPU compiler accepts, and fall back to interpret mode
+off-TPU so the same call sites work in tests (CPU), dry-runs, and on real
+hardware.
+
+Row blocks follow the TPU tiling rule: a block's second-minor dim is a
+multiple of the dtype's sublane tile (8 rows of f32; 32 of int8), or equals
+the whole array's.  A leaf with at most ``block_rows`` rows is one block;
+a larger one is zero-padded up to a multiple of ``block_rows`` (itself a
+tile multiple) and the padding is sliced off the output.  Every kernel here
+is row-wise, so padding never changes the real rows' values.
 
     fedavg_accum(acc, theta, n_old, n_k)        — any-shape pytree leaf
     dequant_merge(acc, q, g, scale, n_old, n_k) — any-shape pytree leaf
@@ -40,6 +48,16 @@ def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
+def _row_block(rows: int, block_rows: int, tile: int) -> tuple[int, int]:
+    """(block, padded_rows) for a row-blocked kernel: the whole array when
+    it fits one block, else ``block_rows`` rounded down to the sublane
+    ``tile`` with ``rows`` padded up to a multiple of it."""
+    if rows <= block_rows:
+        return rows, rows
+    block = max(tile, block_rows // tile * tile)
+    return block, _round_up(rows, block)
+
+
 @functools.partial(jax.jit, static_argnames=("block_rows",))
 def fedavg_accum(acc, theta, n_old, n_k, *, block_rows: int = 256):
     """Streaming Eq. 1 update on one pytree leaf of any shape."""
@@ -48,11 +66,8 @@ def fedavg_accum(acc, theta, n_old, n_k, *, block_rows: int = 256):
     flat_t = theta.astype(dtype).reshape(-1)
     n = flat_a.size
     lanes = _fa.LANES
-    rows = max(1, _round_up(n, lanes) // lanes)
-    # pick a block that divides rows
-    block = min(block_rows, rows)
-    while rows % block:
-        block -= 1
+    block, rows = _row_block(max(1, _round_up(n, lanes) // lanes),
+                             block_rows, 8)
     pad = rows * lanes - n
     if pad:
         flat_a = jnp.pad(flat_a, (0, pad))
@@ -75,10 +90,9 @@ def dequant_merge(acc, q, g, scale, n_old, n_k, *, block_rows: int = 256):
     flat_g = g.astype(dtype).reshape(-1)
     n = flat_a.size
     lanes = _dm.LANES
-    rows = max(1, _round_up(n, lanes) // lanes)
-    block = min(block_rows, rows)
-    while rows % block:
-        block -= 1
+    # int8 q packs 32 rows per sublane tile
+    block, rows = _row_block(max(1, _round_up(n, lanes) // lanes),
+                             block_rows, 32)
     pad = rows * lanes - n
     if pad:
         flat_a = jnp.pad(flat_a, (0, pad))
@@ -98,12 +112,12 @@ def rmsnorm(x, scale, *, eps: float = 1e-6, block_rows: int = 128):
     d = shape[-1]
     rows = max(1, x.size // d)
     x2 = x.reshape(rows, d)
-    block = min(block_rows, rows)
-    while rows % block:
-        block -= 1
+    block, padded = _row_block(rows, block_rows, 8)
+    if padded != rows:
+        x2 = jnp.pad(x2, ((0, padded - rows), (0, 0)))
     out = _rn.rmsnorm_2d(x2, scale, eps=eps, block_rows=block,
                          interpret=INTERPRET)
-    return out.reshape(shape)
+    return out[:rows].reshape(shape)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k"))

@@ -10,7 +10,7 @@ with the inter-chunk SSM state [p, n] carried in VMEM scratch across chunk
 steps — the state never round-trips to HBM (the scan-based version writes
 [b, nc, h, p, n] states out of the loop).  Per chunk step:
 
-  1. la = cumsum(dt * A)                              (decay prefix, VPU)
+  1. la = cumsum(dt * A)  as a masked row sum         (decay prefix, VPU)
   2. y_intra = ((C Bᵀ) ⊙ L) (dt ⊙ x)                  (MXU, [Q,Q]@[Q,p])
   3. y_inter = exp(la) ⊙ (C @ stateᵀ)                 (MXU, [Q,n]@[n,p])
   4. state  = exp(la_Q) state + Bᵀ(decay ⊙ dt ⊙ x)    (MXU, [n,Q]@[Q,p])
@@ -37,6 +37,7 @@ __all__ = ["ssd_bhsp"]
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, o_ref, state_ref, *,
             chunk: int):
+    ih = pl.program_id(1)
     ic = pl.program_id(2)
 
     @pl.when(ic == 0)
@@ -45,21 +46,24 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, o_ref, state_ref, *,
 
     x = x_ref[0, 0].astype(jnp.float32)              # [Q, p]
     dt = dt_ref[0, 0].astype(jnp.float32)            # [Q, 1]
-    A = -jnp.exp(a_ref[0].astype(jnp.float32))       # scalar (as [1])
+    A = -jnp.exp(jnp.full((1, 1), a_ref[ih]))       # this head's decay
     B = b_ref[0, 0].astype(jnp.float32)              # [Q, n]
     C = c_ref[0, 0].astype(jnp.float32)              # [Q, n]
-    D = d_ref[0].astype(jnp.float32)                 # [1]
+    D = d_ref[ih]                                    # scalar skip weight
 
-    la = jnp.cumsum(dt * A, axis=0)                  # [Q, 1]
+    ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    mask = ii >= jj
+    # decay prefix la_i = sum_{j<=i} dt_j A as a masked row sum (Mosaic has
+    # no cumsum)
+    la = jnp.sum(jnp.where(mask, (dt * A).reshape(1, chunk), 0.0), axis=1,
+                 keepdims=True)                      # [Q, 1]
     xbar = x * dt                                    # [Q, p]
 
     # intra-chunk: ((C B^T) ⊙ L) @ xbar
     cb = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)  # [Q, Q]
     ldiff = la - la.reshape(1, chunk)                # la_i - la_j
-    ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    mask = ii >= jj
     decay = jnp.where(mask, jnp.exp(jnp.where(mask, ldiff, 0.0)), 0.0)
     y = jax.lax.dot_general(cb * decay, xbar, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)   # [Q, p]
@@ -103,7 +107,9 @@ def ssd_bhsp(x, dt, A_log, B, C, D, *, chunk: int = 128,
                            lambda ib, ih, ic: (ib, ih, ic, 0))
     bc_spec = pl.BlockSpec((1, 1, chunk, n),
                            lambda ib, ih, ic: (ib, ih // hpg, ic, 0))
-    h_spec = pl.BlockSpec((1,), lambda ib, ih, ic: (ih,))
+    # Per-head scalars live whole in SMEM: a (1,)-block of an [h] vector
+    # breaks the TPU's 128-lane tiling rule for rank-1 blocks.
+    h_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
 
     kern = functools.partial(_kernel, chunk=chunk)
     return pl.pallas_call(
@@ -114,4 +120,4 @@ def ssd_bhsp(x, dt, A_log, B, C, D, *, chunk: int = 128,
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
-    )(x, dt3, A_log, B, C, D)
+    )(x, dt3, A_log.astype(jnp.float32), B, C, D.astype(jnp.float32))

@@ -33,7 +33,7 @@ import traceback
 
 
 from repro.configs import ARCH_NAMES, SHAPES, get_arch
-from repro.launch.hlo_cost import analyze_hlo, xla_cost_dict
+from repro.launch.hlo_cost import analyze_hlo
 from repro.launch.mesh import axis_sizes, make_production_mesh
 from repro.launch.plan import (make_plan, param_bytes, runnable,
                                sharding_specs, skip_reason)
@@ -74,7 +74,7 @@ def run_cell(arch: str, shape: str, mesh_kind: str, *, hlo_dir=None,
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
     mem = _mem_dict(compiled.memory_analysis())
-    cost = xla_cost_dict(compiled)
+    cost = compiled.cost_analysis() or {}
     hlo = compiled.as_text()
     pod_size = ax["data"] * ax["model"] if "pod" in ax else 0
     # Trip-count-aware walker (XLA's cost_analysis counts while bodies once —

@@ -31,17 +31,7 @@ from __future__ import annotations
 import re
 from dataclasses import dataclass, field
 
-__all__ = ["analyze_hlo", "HloCost", "CollectiveCall", "xla_cost_dict"]
-
-
-def xla_cost_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` normalized across jax versions: a dict in
-    jax >= 0.5, a one-element list of dicts in 0.4.x, ``None`` on backends
-    without the analysis."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost or {})
+__all__ = ["analyze_hlo", "HloCost", "CollectiveCall"]
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2,

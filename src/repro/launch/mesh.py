@@ -15,31 +15,21 @@ Pollen's FL workers; the model axis carries TP/EP; FSDP uses (pod, data).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_test_mesh", "axis_sizes",
-           "mesh_axis_types_kwargs", "fl_shard_devices",
-           "fl_combine_topology"]
-
-
-def mesh_axis_types_kwargs(axes) -> dict:
-    """``axis_types=`` kwargs for :func:`jax.make_mesh`, or ``{}`` on jax
-    versions (< 0.5) that predate ``jax.sharding.AxisType`` — where every
-    mesh axis is implicitly Auto anyway."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * len(axes)}
+           "fl_shard_devices", "fl_combine_topology"]
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **mesh_axis_types_kwargs(axes))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(shape=(1, 1), axes=("data", "model")):
     """Tiny mesh over however many (host) devices exist — smoke tests."""
-    return jax.make_mesh(shape, axes, **mesh_axis_types_kwargs(axes))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def axis_sizes(mesh) -> dict:

@@ -1,9 +1,15 @@
-"""Deterministic process-per-host simulation harness (one box).
+"""Deterministic process-per-host simulation harness (one box, CPU only).
 
 ``EngineConfig.hosts=H`` gives the in-process engine a host level above
 the shard→root combine tree; this module runs the *same arithmetic* as H
-spawned OS processes, one per host group.  The design keeps every rank's
-round loop bit-identical to the single-process engine:
+spawned OS processes, one per host group — a fault-injection harness for
+the host exchange, the sidecar channel and rank deaths.  Every rank runs
+on the CPU: the spawner sets ``JAX_PLATFORMS=cpu`` in the ranks'
+environment, since a TPU chip belongs to one process and H ranks cannot
+share it.  On the chip, ``hosts=H`` runs as the single-process engine
+(``--hosts H`` of ``repro.launch.train``), which computes the same
+losses.  The design keeps every rank's round loop bit-identical to the
+single-process engine:
 
 * **Replicated producers** — every rank builds the engine from the same
   picklable ``(builder, kwargs)`` pair, so sampling, placement, packing
@@ -143,17 +149,27 @@ def run_multihost(builder, kwargs, *, hosts, rounds, resume=False,
             f"harness was asked for {hosts} ranks — they must match")
     ctx = mp.get_context("spawn")
     conns, procs = [], []
-    for rank in range(hosts):
-        parent_c, child_c = ctx.Pipe()
-        p = ctx.Process(
-            target=_child_main,
-            args=(child_c, rank, builder, dict(kwargs), int(rounds),
-                  bool(resume), kill_at),
-            name=f"pollen-host{rank}", daemon=True)
-        p.start()
-        child_c.close()
-        conns.append(parent_c)
-        procs.append(p)
+    # Spawned interpreters inherit os.environ as it is at start(), so each
+    # rank sees JAX_PLATFORMS=cpu before it imports JAX.
+    saved = os.environ.get("JAX_PLATFORMS")
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    try:
+        for rank in range(hosts):
+            parent_c, child_c = ctx.Pipe()
+            p = ctx.Process(
+                target=_child_main,
+                args=(child_c, rank, builder, dict(kwargs), int(rounds),
+                      bool(resume), kill_at),
+                name=f"pollen-host{rank}", daemon=True)
+            p.start()
+            child_c.close()
+            conns.append(parent_c)
+            procs.append(p)
+    finally:
+        if saved is None:
+            del os.environ["JAX_PLATFORMS"]
+        else:
+            os.environ["JAX_PLATFORMS"] = saved
 
     out = MultihostResult(ok=True, hosts=hosts)
     done: dict[int, tuple] = {}

@@ -34,6 +34,7 @@ from repro.core import (EngineConfig, FederatedEngine, SyntheticTelemetry,
 from repro.data import make_federated_dataset
 from repro.distributed import FailureEvent, WorkerPool
 from repro.fl.strategy import FedAvg, FedMedian
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params, make_loss_fn
 from repro.models.papertasks import TASK_MODELS, make_task_model
 from repro.obs import make_observability, write_trace
@@ -427,6 +428,7 @@ def main() -> int:
     if args.print_flags_md:
         print(flags_markdown())
         return 0
+    enable_compile_cache()
 
     obs = None
     if args.trace_out or args.flight_rounds > 0:

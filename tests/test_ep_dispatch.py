@@ -8,13 +8,12 @@ import sys
 
 SCRIPT = r"""
 import jax, jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 from repro.distributed.ep_dispatch import make_ep_dispatch
 from repro.models.layers import moe_layer_3d
 
-from repro.launch.mesh import mesh_axis_types_kwargs
 mesh = jax.make_mesh((2, 4), ('data', 'model'),
-                     **mesh_axis_types_kwargs(('data', 'model')))
+                     axis_types=(AxisType.Auto,) * 2)
 b, s, D, E, F, k = 4, 16, 32, 8, 16, 2
 ks = jax.random.split(jax.random.key(0), 5)
 x = jax.random.normal(ks[0], (b, s, D))

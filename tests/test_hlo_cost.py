@@ -5,7 +5,7 @@ XLA's own cost_analysis on unrolled modules and correctly scale rolled scans
 import jax
 import jax.numpy as jnp
 
-from repro.launch.hlo_cost import analyze_hlo, xla_cost_dict
+from repro.launch.hlo_cost import analyze_hlo
 
 
 def _scan_fn(unroll):
@@ -21,7 +21,7 @@ def test_walker_matches_xla_on_unrolled():
     x = jax.ShapeDtypeStruct((128, 128), jnp.float32)
     w = jax.ShapeDtypeStruct((10, 128, 128), jnp.float32)
     c = jax.jit(_scan_fn(True)).lower(x, w).compile()
-    xla = float(xla_cost_dict(c)["flops"])
+    xla = float(c.cost_analysis()["flops"])
     mine = analyze_hlo(c.as_text()).flops
     assert abs(mine - xla) / xla < 0.02
 
@@ -35,7 +35,7 @@ def test_walker_scales_scan_by_trip_count():
     f_unrolled = analyze_hlo(unrolled.as_text()).flops
     assert abs(f_rolled - f_unrolled) / f_unrolled < 0.02
     # XLA's own count misses the 10x
-    assert float(xla_cost_dict(rolled)["flops"]) < 0.2 * f_rolled
+    assert float(rolled.cost_analysis()["flops"]) < 0.2 * f_rolled
 
 
 def test_nested_scan_multiplicity():
@@ -78,10 +78,9 @@ def test_collectives_with_multiplicity():
     import os
     script = r"""
 import jax, jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.launch.hlo_cost import analyze_hlo, xla_cost_dict
-from repro.launch.mesh import mesh_axis_types_kwargs
-mesh = jax.make_mesh((2, 2), ("a", "b"), **mesh_axis_types_kwargs("ab"))
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+from repro.launch.hlo_cost import analyze_hlo
+mesh = jax.make_mesh((2, 2), ("a", "b"), axis_types=(AxisType.Auto,) * 2)
 def f(x, w):
     def body(c, wi):
         return c @ wi, None

@@ -7,6 +7,11 @@ plane live.  Measured mode on a mesh records exact per-worker wall times;
 the round-level predicted-share attribution path is never used.
 """
 
+import json
+import os
+import subprocess
+import sys
+
 import jax
 import numpy as np
 import pytest
@@ -26,7 +31,8 @@ from repro.optim import sgd
 def _engine(mesh=0, depth=1, cache=0, placement="lb", telemetry="synthetic",
             drift=0.0, adapt=0, sampler="uniform", affinity=False,
             granularity="type", strategy=None, workers=4, bucket="round",
-            combine="flat", compress="none", pool=None, steps_cap=4):
+            combine="flat", compress="none", hosts=0, pool=None,
+            steps_cap=4):
     ds = make_federated_dataset("sr", n_clients=64, input_dim=16,
                                 batch_size=4, size_mu=2.5, size_sigma=0.8)
     params, loss = make_task_model("sr", jax.random.key(0), input_dim=16,
@@ -46,7 +52,7 @@ def _engine(mesh=0, depth=1, cache=0, placement="lb", telemetry="synthetic",
                             device_cache_batches=cache,
                             cache_affinity=affinity,
                             bucket_mode=bucket, combine_mode=combine,
-                            combine_compress=compress,
+                            combine_compress=compress, hosts=hosts,
                             telemetry_mode=telemetry,
                             drift_threshold=drift, adapt_interval=adapt,
                             adapt_granularity=granularity))
@@ -284,3 +290,56 @@ def test_adapt_granularity_worker_moves_single_wid():
     # the last move landed on exactly that worker's pool entry
     _, key, _, new = traj[-1]
     assert eng.pool.workers[int(key[1:])].concurrency == new
+
+
+# -- one worker program per device -------------------------------------------
+
+_MESH4_SCRIPT = r"""
+import json, sys
+import jax
+from test_mesh import _engine
+
+assert len(jax.devices()) == 4, jax.devices()
+
+out = {"fused": [r.loss for r in _engine(mesh=0).run(3)]}
+for name, kw in (("flat", {}), ("tree_hosts2", dict(combine="tree", hosts=2))):
+    eng = _engine(mesh=4, **kw)
+    losses = [eng.run(1)[0].loss]
+    st = eng.compile_stats
+    warm = (st["compiles"], st["executables"])
+    losses += [r.loss for r in eng.run(2)]
+    st = eng.compile_stats
+    out[name] = dict(
+        losses=losses, warm=warm, after=(st["compiles"], st["executables"]),
+        worker_executables=st["worker_step"]["executables"],
+        shard_devices=len({str(d) for d in eng._shard_devices}))
+print(json.dumps(out))
+"""
+
+
+def test_mesh_workers_on_four_devices():
+    """``mesh_workers=4`` on a 4-device host: each worker program runs on
+    its own device, partials cross to the root for the flat combine and
+    for the shard → host → root tree, and no round after round 0
+    compiles — neither a new step-cache entry nor a hidden jit recompile
+    from a change of argument placement.  Flat matches the fused run
+    bitwise; tree/hosts to float tolerance (as in the one-device tests).
+    Runs in a subprocess so the 4 virtual CPU devices do not leak into the
+    1-device test session."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(here, "..", "src"), here, env.get("PYTHONPATH", "")])
+    proc = subprocess.run([sys.executable, "-c", _MESH4_SCRIPT], env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    fused = out["fused"]
+    assert out["flat"]["losses"] == fused
+    assert np.allclose(out["tree_hosts2"]["losses"], fused, rtol=1e-5)
+    for name in ("flat", "tree_hosts2"):
+        run = out[name]
+        assert run["shard_devices"] == 4, name
+        assert run["worker_executables"] == 4, name   # one per device
+        assert run["after"] == run["warm"], name
