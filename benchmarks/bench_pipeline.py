@@ -190,7 +190,7 @@ def _engine_comparison(*, rounds: int, repeats: int = 3) -> dict:
     # training (losses bit-identical to the untraced run) and its wall
     # overhead must stay inside the gated budget (benchmarks.perf_gate:
     # <= 2% relative, with an absolute noise floor)
-    from repro.obs import make_observability, write_trace
+    from repro.obs import make_observability, profiler_trace
 
     obs = make_observability(trace_rounds=rounds + 4)
     eng = _build_engine(depth=1, obs=obs)
@@ -211,7 +211,10 @@ def _engine_comparison(*, rounds: int, repeats: int = 3) -> dict:
     out["tracer_overhead_fraction"] = max(0.0, (traced_wall - base) / base)
     trace_out = os.environ.get("POLLEN_TRACE_OUT")
     if trace_out:
-        write_trace(trace_out, obs.tracer.snapshot())
+        # A profiler trace (a directory) of two more traced rounds, after
+        # the timing: the engine's spans beside the device's operations.
+        with profiler_trace(trace_out):
+            eng.run(2)
     return out
 
 

@@ -1008,6 +1008,9 @@ class FederatedEngine:
         plan = plan_round(assignment, workers,
                           lanes_per_worker=self.cfg.lanes_per_worker,
                           steps_cap=self.cfg.steps_cap, min_steps=1)
+        # The host's blocking fetch of the data generator's output: the
+        # generator runs on the device, queued behind the round in flight.
+        fetch = tr.span("wait.generator", t=t)
         cache_plan = None
         worker_programs = None
         combine_masks = None
@@ -1036,10 +1039,11 @@ class FederatedEngine:
                         self.dataset, plan=plan,
                         batch_size=self.cfg.batch_size,
                         seq_len=self.cfg.seq_len,
-                        s_align=lambda s: S, buffers=self._pack_buffers)
+                        s_align=lambda s: S, buffers=self._pack_buffers,
+                        fetch_span=fetch)
                 worker_programs = self._pack_worker_programs(
                     t, plan, worker_S, arrays, assignment, workers,
-                    mesh_map, loads)
+                    mesh_map, loads, fetch)
             pack_s = time.perf_counter() - tp0
             with tr.span("prep.h2d", t=t):
                 root = self._combine_root
@@ -1075,13 +1079,15 @@ class FederatedEngine:
                 host_batches = gather_content_rows(
                     self.dataset, plan, cache_plan.content_mask,
                     cache_plan.n_miss_rows, batch_size=self.cfg.batch_size,
-                    seq_len=self.cfg.seq_len, buffers=self._pack_buffers)
+                    seq_len=self.cfg.seq_len, buffers=self._pack_buffers,
+                    fetch_span=fetch)
             else:
                 arrays = build_round_arrays(
                     self.dataset, plan=plan,
                     batch_size=self.cfg.batch_size,
                     seq_len=self.cfg.seq_len,
-                    s_align=self._s_align, buffers=self._pack_buffers)
+                    s_align=self._s_align, buffers=self._pack_buffers,
+                    fetch_span=fetch)
                 host_batches = arrays.batches
         pack_s = time.perf_counter() - tp0
         # Explicit async H2D: transfers overlap the in-flight round's compute.
@@ -1108,7 +1114,7 @@ class FederatedEngine:
                               online_pool=online_pool)
 
     def _pack_worker_programs(self, t, plan, worker_S, arrays, assignment,
-                              workers, mesh_map, loads):
+                              workers, mesh_map, loads, fetch):
         """Producer half of the mesh path: one (device-arrays, cache-plan)
         bundle per worker, H2D'd to that worker's shard device.
 
@@ -1120,7 +1126,8 @@ class FederatedEngine:
         round's full S and sliced ``[:, :, :S_w]`` per worker (numpy views
         — no copies before the transfer).  With the device cache on, each
         worker's content travels as its own compact miss array planned
-        against its shard's pool at that worker's S."""
+        against its shard's pool at that worker's S; ``fetch`` is the span
+        around each blocking fetch of the generator's output."""
         order = sorted(workers, key=lambda w: w.wid)
         subplans = (split_plan_by_worker(plan)
                     if self._device_cache is not None else None)
@@ -1155,7 +1162,8 @@ class FederatedEngine:
                 miss = gather_content_rows(
                     self.dataset, subplans[wi], cplan.content_mask,
                     cplan.n_miss_rows, batch_size=self.cfg.batch_size,
-                    seq_len=self.cfg.seq_len, buffers=self._pack_buffers)
+                    seq_len=self.cfg.seq_len, buffers=self._pack_buffers,
+                    fetch_span=fetch)
                 batches_d = jax.device_put(miss, dev)
             else:
                 cplan = None
@@ -1709,7 +1717,8 @@ class FederatedEngine:
                 if queue:
                     w0 = time.perf_counter()
                     try:
-                        next_prep = queue.popleft().result()
+                        with self._tracer.span("exec.wait_prep", t=t + 1):
+                            next_prep = queue.popleft().result()
                     except Exception as e:     # noqa: BLE001
                         # Round t already executed — book it before raising,
                         # or a retrying caller would train round t twice.

@@ -239,12 +239,15 @@ class PackBuffers:
                 slot["weight"])
 
 
-def _batch_content(dataset, cids, batch_idx, *, batch_size, seq_len) -> dict:
+def _batch_content(dataset, cids, batch_idx, *, batch_size, seq_len,
+                   fetch_span=None) -> dict:
     """Bulk-fetch N batches; falls back to a per-batch loop for datasets
-    (e.g. thin wrappers) that do not implement ``gather_batches``."""
+    (e.g. thin wrappers) that do not implement ``gather_batches``.
+    ``fetch_span`` goes to ``gather_batches`` (see there)."""
     gather = getattr(dataset, "gather_batches", None)
     if gather is not None:
-        return gather(cids, batch_idx, batch_size=batch_size, seq_len=seq_len)
+        return gather(cids, batch_idx, batch_size=batch_size, seq_len=seq_len,
+                      fetch_span=fetch_span)
     rows: dict[str, list] = {}
     for cid, bi in zip(cids.tolist(), batch_idx.tolist()):
         b = dataset.client_batch(cid, bi, batch_size=batch_size,
@@ -261,7 +264,8 @@ def build_round_arrays(dataset, assignment=None, workers=None, *,
                        seq_len: int | None = None, min_steps: int = 1,
                        s_align=None,
                        buffers: PackBuffers | None = None,
-                       plan: RoundPlan | None = None) -> RoundArrays:
+                       plan: RoundPlan | None = None,
+                       fetch_span=None) -> RoundArrays:
     """Materialize padded [W, P, S, ...] stream arrays for an assignment.
 
     ``s_align``: optional ``f(s_real) -> S`` (e.g. the engine's s_bucket) —
@@ -269,6 +273,8 @@ def build_round_arrays(dataset, assignment=None, workers=None, *,
     ever happens downstream.  ``buffers``: optional :class:`PackBuffers` to
     reuse host allocations across rounds.  ``plan``: optional precomputed
     :class:`RoundPlan`; when given, ``assignment``/``workers`` are ignored.
+    ``fetch_span``: entered around the blocking fetch of the generator's
+    output (:meth:`FederatedDataset.gather_batches`).
     (The engine's device-cache path does not use this full packer at all —
     see :func:`build_round_masks` + :func:`gather_content_rows`.)
     """
@@ -285,7 +291,8 @@ def build_round_arrays(dataset, assignment=None, workers=None, *,
                  if buffers is not None else None)
     if plan.n_steps_total:
         vals = _batch_content(dataset, plan.cids, plan.batch_idx,
-                              batch_size=batch_size, seq_len=seq_len)
+                              batch_size=batch_size, seq_len=seq_len,
+                              fetch_span=fetch_span)
         row_specs = [(name, tuple(arr.shape[1:]), arr.dtype)
                      for name, arr in vals.items()]
     else:
@@ -346,7 +353,8 @@ def build_round_masks(plan: RoundPlan, S: int, *,
 def gather_content_rows(dataset, plan: RoundPlan, sel, n_rows: int, *,
                         batch_size: int | None = None,
                         seq_len: int | None = None,
-                        buffers: PackBuffers | None = None) -> dict:
+                        buffers: PackBuffers | None = None,
+                        fetch_span=None) -> dict:
     """Compact ``{name: [n_rows, ...]}`` content for the selected steps.
 
     ``sel``: bool [N] step mask (None = every step); rows keep plan-step
@@ -357,6 +365,7 @@ def gather_content_rows(dataset, plan: RoundPlan, sel, n_rows: int, *,
     Padding rows carry dummy content; the device-side scatter drops them
     via out-of-bounds destinations.  With ``buffers``, leaf shapes for an
     all-padding result come from ``row_memo`` instead of a dataset probe.
+    ``fetch_span`` as in :func:`build_round_arrays`.
     """
     cids = plan.cids if sel is None else plan.cids[sel]
     bidx = plan.batch_idx if sel is None else plan.batch_idx[sel]
@@ -370,7 +379,8 @@ def gather_content_rows(dataset, plan: RoundPlan, sel, n_rows: int, *,
             cids = np.concatenate([cids, np.zeros(pad, cids.dtype)])
             bidx = np.concatenate([bidx, np.zeros(pad, bidx.dtype)])
         out = _batch_content(dataset, cids, bidx,
-                             batch_size=batch_size, seq_len=seq_len)
+                             batch_size=batch_size, seq_len=seq_len,
+                             fetch_span=fetch_span)
         row_specs = [(name, tuple(arr.shape[1:]), arr.dtype)
                      for name, arr in out.items()]
     else:

@@ -17,6 +17,7 @@ Clients below one full batch are excluded (paper §5.1).
 
 from __future__ import annotations
 
+from contextlib import nullcontext
 from dataclasses import dataclass, replace
 
 import jax
@@ -179,7 +180,7 @@ class FederatedDataset:
 
     # -- bulk fetch (the round packer's fast path) -------------------------
     def gather_batches(self, cids, batch_idxs, *, batch_size=None,
-                       seq_len=None) -> dict:
+                       seq_len=None, fetch_span=None) -> dict:
         """Materialize many (client, batch) pairs in one fused device call.
 
         Returns ``{name: [N, ...]}`` bit-identical to stacking N
@@ -188,6 +189,10 @@ class FederatedDataset:
         replaces) collapses into one jitted vmap.  The jit cache is bounded
         by rounding N up to the next power of two (extra rows are computed
         for (0, 0) and sliced off).
+
+        ``fetch_span``: a context entered around the host's blocking fetch
+        of the generator's output (the engine's ``wait.generator`` span);
+        the generator's dispatch stays outside it.
         """
         cids = np.asarray(cids, dtype=np.int64)
         bis = np.asarray(batch_idxs, dtype=np.int64)
@@ -215,9 +220,9 @@ class FederatedDataset:
             rows = cids % len(self._class_logits)
             args.append(jnp.asarray(self._class_logits[rows],
                                     dtype=jnp.float32))
-        fn = self._bulk_fn(bs, sl)
-        out = fn(*args)
-        return {k: np.asarray(v)[:n] for k, v in out.items()}
+        out = self._bulk_fn(bs, sl)(*args)
+        with fetch_span or nullcontext():
+            return {k: np.asarray(v)[:n] for k, v in out.items()}
 
     def _bulk_fn(self, bs: int, sl: int):
         cache = getattr(self, "_bulk_cache", None)
@@ -237,12 +242,12 @@ class FederatedDataset:
                 return self._content(cid32, bi32, 0,
                                      extra if labelled else None, bs, sl)
 
-            n_extra = 1 if (kind == "tokens" or labelled) else 0
-            if n_extra:
-                fn = jax.jit(jax.vmap(elem))
-            else:
-                fn = jax.jit(jax.vmap(lambda c, b: elem(c, b)))
-            cache[(bs, sl)] = fn
+            def gen_batches(cid32, bi32, *extra):
+                # Named so the program reads ``jit_gen_batches`` in a
+                # profiler trace.
+                return jax.vmap(elem)(cid32, bi32, *extra)
+
+            fn = cache[(bs, sl)] = jax.jit(gen_batches)
         return fn
 
 

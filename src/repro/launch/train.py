@@ -21,6 +21,7 @@ import argparse
 import json
 import signal
 from collections import Counter
+from contextlib import nullcontext
 from dataclasses import replace
 
 import jax
@@ -37,7 +38,7 @@ from repro.fl.strategy import FedAvg, FedMedian
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params, make_loss_fn
 from repro.models.papertasks import TASK_MODELS, make_task_model
-from repro.obs import make_observability, write_trace
+from repro.obs import make_observability, profiler_trace
 from repro.optim import adam, sgd
 
 __all__ = ["build_engine", "main", "flags_markdown", "PRESETS"]
@@ -70,11 +71,11 @@ class _FrontendDataset:
         return {k: v[0] for k, v in out.items()}
 
     def gather_batches(self, cids, batch_idxs, *, batch_size=None,
-                       seq_len=None):
+                       seq_len=None, fetch_span=None):
         """Bulk fetch (the vectorized packer's fast path): token content from
         the base dataset plus the vmapped frontend-stub arrays."""
         b = self.base.gather_batches(cids, batch_idxs, batch_size=batch_size,
-                                     seq_len=seq_len)
+                                     seq_len=seq_len, fetch_span=fetch_span)
         cfg = self.cfg
         if not cfg.frontend:
             return b
@@ -100,7 +101,8 @@ class _FrontendDataset:
         stub = jax.vmap(lambda f: jax.random.normal(
             jax.random.fold_in(jax.random.key(7), f), shape, np.float32))(
                 jnp.asarray(folds))
-        b[name] = np.asarray(stub)
+        with fetch_span or nullcontext():
+            b[name] = np.asarray(stub)
         return b
 
 
@@ -372,13 +374,16 @@ def _build_parser() -> argparse.ArgumentParser:
                          "--mesh-workers >= 2, and shards/H a power of "
                          "two; see launch/multihost.py for the "
                          "process-per-host harness")
-    ap.add_argument("--trace-out", default=None,
-                    help="write a Chrome/Perfetto trace.json of the run's "
-                         "span timeline (producer pack, per-worker sync, "
-                         "combine, controller decisions, counter tracks); "
-                         "load it at ui.perfetto.dev — see "
-                         "docs/OBSERVABILITY.md.  Tracing never perturbs "
-                         "results (bit-identity is test-enforced)")
+    ap.add_argument("--trace-out", default=None, metavar="DIR",
+                    help="take a jax.profiler trace of the run into DIR: "
+                         "host and device on one clock, the engine's spans "
+                         "(producer prep, generator wait, dispatch, device "
+                         "wait) on the host threads; DIR/plugins/profile/"
+                         "TIME/ holds the .xplane.pb and a "
+                         "perfetto_trace.json.gz that loads at "
+                         "ui.perfetto.dev — see docs/OBSERVABILITY.md.  "
+                         "Tracing never perturbs results (bit-identity is "
+                         "test-enforced)")
     ap.add_argument("--trace-rounds", type=int, default=64,
                     help="rounds of spans each tracer lane retains (ring "
                          "buffer; older spans are dropped, counted, never "
@@ -480,7 +485,8 @@ def main() -> int:
     if args.resume and engine.restore_latest():
         print(f"resumed from round {engine.round_idx}")
 
-    results = engine.run(args.rounds, log_every=1)
+    with profiler_trace(args.trace_out) if args.trace_out else nullcontext():
+        results = engine.run(args.rounds, log_every=1)
     summary = {
         "rounds": len(results),
         "final_loss": results[-1].loss if results else None,
@@ -542,9 +548,7 @@ def main() -> int:
         summary["fallback_rounds"] = int(sum(
             r.drift_fallback for r in results))
     if args.trace_out:
-        recs = obs.tracer.snapshot()
-        write_trace(args.trace_out, recs)
-        print(f"trace: wrote {len(recs)} records to {args.trace_out}")
+        print(f"trace: wrote a profiler trace under {args.trace_out}")
     print(json.dumps(summary, indent=1))
     if args.metrics_out:
         with open(args.metrics_out, "w") as f:

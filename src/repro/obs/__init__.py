@@ -1,5 +1,5 @@
-"""Observability plane: span tracer, metrics registry, round critique,
-Perfetto export, and the flight recorder.
+"""Observability plane: span tracer (mirrored into the ``jax.profiler``
+trace), metrics registry, round critique, and the flight recorder.
 
 One :class:`Observability` bundle rides the engine as a single optional
 kwarg; ``make_observability`` builds a fully wired one.  When absent the
@@ -14,13 +14,12 @@ from dataclasses import dataclass
 from repro.obs.critique import RoundCritique, critique_round
 from repro.obs.flight import FlightRecorder
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.perfetto import trace_events, write_trace
-from repro.obs.tracer import NULL_TRACER, NullTracer, Tracer
+from repro.obs.tracer import NULL_TRACER, NullTracer, Tracer, profiler_trace
 
 __all__ = ["Tracer", "NullTracer", "NULL_TRACER", "MetricsRegistry",
            "RoundCritique", "critique_round", "FlightRecorder",
-           "trace_events", "write_trace", "Observability",
-           "make_observability", "SPANS_PER_ROUND"]
+           "Observability", "make_observability", "profiler_trace",
+           "SPANS_PER_ROUND"]
 
 # Ring sizing: a round books ~a dozen producer spans, a sync span per
 # worker, a few counters — 64 per retained round is a comfortable bound.

@@ -1,4 +1,5 @@
-"""Zero-dependency span tracer: preallocated per-thread ring buffers.
+"""Span tracer: preallocated per-thread ring buffers, mirrored onto the
+JAX profiler's clock.
 
 Design constraints (the engine's ordering discipline dictates them):
 
@@ -12,15 +13,21 @@ Design constraints (the engine's ordering discipline dictates them):
   losses stay bit-identical with the tracer on or off (test-enforced).
 * **Lanes are thread names.**  The producer's spans land on the
   ``pollen-pack*`` lane, per-shard sync spans on ``pollen-sync*`` lanes,
-  consumer spans on ``MainThread`` — which is exactly the Perfetto track
-  layout.  :meth:`Tracer.add_span` books a span retroactively on an
-  explicit lane (the engine uses it for per-worker sync windows, whose
-  durations it already measures for telemetry).
+  consumer spans on ``MainThread``.  :meth:`Tracer.add_span` books a span
+  retroactively on an explicit lane (the engine uses it for per-worker
+  sync windows, whose durations it already measures for telemetry).
+* **One clock with the device.**  A live :meth:`Tracer.span` also enters
+  a ``jax.profiler.TraceAnnotation`` of the span's bare name on its
+  thread, so under a ``jax.profiler`` trace the engine's spans sit on the
+  host plane beside the device's operations.  Retroactive
+  :meth:`Tracer.add_span` records, instants and counters stay in the ring
+  only: the profiler stamps an annotation when it is entered and left,
+  and cannot take a timestamp after the fact.
 
-Record format (shared with :mod:`repro.obs.perfetto` and the flight
-recorder): ``(ph, name, t0, dur_or_value, lane, depth, attrs)`` where
-``ph`` is ``"X"`` (duration span), ``"I"`` (instant), or ``"C"``
-(counter sample); ``t0`` is a ``time.perf_counter()`` timestamp.
+Record format (shared with the flight recorder):
+``(ph, name, t0, dur_or_value, lane, depth, attrs)`` where ``ph`` is
+``"X"`` (duration span), ``"I"`` (instant), or ``"C"`` (counter sample);
+``t0`` is a ``time.perf_counter()`` timestamp.
 """
 
 from __future__ import annotations
@@ -28,7 +35,22 @@ from __future__ import annotations
 import threading
 import time
 
-__all__ = ["Tracer", "NullTracer", "NULL_TRACER"]
+import jax
+from jax.profiler import TraceAnnotation
+
+__all__ = ["Tracer", "NullTracer", "NULL_TRACER", "profiler_trace"]
+
+
+def profiler_trace(log_dir: str):
+    """Context manager taking a ``jax.profiler`` trace into ``log_dir``
+    (an ``.xplane.pb`` and a ``perfetto_trace.json.gz`` that opens at
+    ui.perfetto.dev), with the profiler's Python tracer off: the engine's
+    spans name the host's phases, and the Python tracer would book every
+    Python call and slow the run several-fold."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    return jax.profiler.trace(log_dir, create_perfetto_trace=True,
+                              profiler_options=opts)
 
 
 class _Ring:
@@ -60,11 +82,12 @@ class _Ring:
 
 
 class _SpanCtx:
-    """Reentrant-per-thread span context: clock read on enter, one ring
-    append on exit.  Depth is tracked per thread so nested spans render
-    as a stack in the Perfetto track."""
+    """Reentrant-per-thread span context: clock read and profiler
+    annotation on enter, one ring append on exit.  Depth is tracked per
+    thread so nested spans keep their stack.  One context may be entered
+    again once it has been left (each entry books its own span)."""
 
-    __slots__ = ("_tracer", "_name", "_attrs", "_t0")
+    __slots__ = ("_tracer", "_name", "_attrs", "_t0", "_ann")
 
     def __init__(self, tracer, name, attrs):
         self._tracer = tracer
@@ -74,11 +97,14 @@ class _SpanCtx:
     def __enter__(self):
         tl = self._tracer._tl()
         tl.depth += 1
+        self._ann = TraceAnnotation(self._name)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
         tl = self._tracer._tl()
         tl.depth -= 1
         tl.ring.append(("X", self._name, self._t0, t1 - self._t0,
@@ -114,7 +140,8 @@ class Tracer:
 
     # -- recording ---------------------------------------------------------
     def span(self, name: str, **attrs) -> _SpanCtx:
-        """Context manager timing a section on the calling thread's lane."""
+        """Context manager timing a section on the calling thread's lane,
+        and annotating it as ``name`` in a running ``jax.profiler`` trace."""
         return _SpanCtx(self, name, attrs or None)
 
     def instant(self, name: str, **attrs) -> None:
@@ -133,7 +160,8 @@ class Tracer:
                  lane: str | None = None, **attrs) -> None:
         """Book an already-measured span retroactively — used for windows
         the engine times anyway (per-worker device sync), on an explicit
-        lane so each worker renders as its own track."""
+        lane so each worker keeps its own track.  Ring only: it cannot
+        reach the profiler's trace."""
         tl = self._tl()
         tl.ring.append(("X", name, float(t0), max(float(dur), 0.0),
                         lane if lane is not None else tl.lane, 0,

@@ -178,10 +178,11 @@ class PopulationDataset:
                                       seq_len=seq_len)
 
     def gather_batches(self, cids, batch_idxs, *, batch_size=None,
-                       seq_len=None):
+                       seq_len=None, fetch_span=None):
         return self.base.gather_batches(cids, batch_idxs,
                                         batch_size=batch_size,
-                                        seq_len=seq_len)
+                                        seq_len=seq_len,
+                                        fetch_span=fetch_span)
 
     def __getattr__(self, name):
         return getattr(self.base, name)

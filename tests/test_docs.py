@@ -52,13 +52,15 @@ def test_population_doc_covers_the_subsystem():
 
 def test_observability_doc_covers_the_plane():
     """docs/OBSERVABILITY.md must keep naming the tracer mechanics, the
-    span taxonomy, the idle-gap formula, the Perfetto workflow, the
-    flight-recorder triggers, and the overhead/trend gates — and stay
-    reachable from README and ARCHITECTURE."""
+    span taxonomy, the spans' mirror on the profiler's clock, the idle-gap
+    formula, the profiler-trace workflow, the flight-recorder triggers,
+    and the overhead/trend gates — and stay reachable from README and
+    ARCHITECTURE."""
     assert check_docs.check_doc_coverage() == []
     assert "docs/OBSERVABILITY.md" in check_docs.DOC_NEEDLES
     for needle in ("Tracer", "FlightRecorder", "make_observability",
                    "bit-identical", "critique_round", "ui.perfetto.dev",
+                   "TraceAnnotation", "wait.generator", "exec.wait_prep",
                    "tracer_overhead_fraction", "SIGTERM",
                    "idle_time / (makespan * n_workers)"):
         assert needle in check_docs.OBSERVABILITY_NEEDLES, needle

@@ -1,13 +1,19 @@
-"""Observability plane: span tracer, Perfetto export, round critique,
-flight recorder — and the tentpole invariant that tracing NEVER perturbs
-training (losses and SLO fields bit-identical with the tracer on or off,
-across pipeline depths and mesh shard counts, controller live)."""
+"""Observability plane: span tracer and its mirror on the profiler's
+clock, round critique, flight recorder — and the tentpole invariant that
+tracing NEVER perturbs training (losses and SLO fields bit-identical with
+the tracer on or off, across pipeline depths and mesh shard counts,
+controller live)."""
 
 import json
+import os
+import subprocess
+import sys
 import threading
+from pathlib import Path
 
 import jax
 import pytest
+from jax.profiler import ProfileData
 
 from repro.core import (EngineConfig, FederatedEngine, SyntheticTelemetry,
                         UniformSampler, make_placement)
@@ -15,13 +21,15 @@ from repro.data import make_federated_dataset
 from repro.distributed import WorkerPool
 from repro.models.papertasks import make_task_model
 from repro.obs import (NULL_TRACER, FlightRecorder, MetricsRegistry, Tracer,
-                       critique_round, make_observability, trace_events,
-                       write_trace)
+                       critique_round, make_observability, profiler_trace)
 from repro.optim import sgd
 
 
+CHECKOUT = Path(__file__).resolve().parents[1]
+
+
 def _engine(mesh=0, depth=1, obs=None, drift=0.0, adapt=0,
-            granularity="type"):
+            granularity="type", cache=0):
     ds = make_federated_dataset("sr", n_clients=64, input_dim=16,
                                 batch_size=4, size_mu=2.5, size_sigma=0.8)
     params, loss = make_task_model("sr", jax.random.key(0), input_dim=16,
@@ -35,7 +43,8 @@ def _engine(mesh=0, depth=1, obs=None, drift=0.0, adapt=0,
         config=EngineConfig(steps_cap=4, batch_size=4, lanes_per_worker=2,
                             pipeline_depth=depth, mesh_workers=mesh,
                             drift_threshold=drift, adapt_interval=adapt,
-                            adapt_granularity=granularity),
+                            adapt_granularity=granularity,
+                            device_cache_batches=cache),
         obs=obs)
 
 
@@ -144,38 +153,6 @@ def test_critique_idle_fraction_and_critical_path():
     assert c2.critical_path == "pack"
 
 
-# -- Perfetto export ----------------------------------------------------------
-
-def test_perfetto_export_schema(tmp_path):
-    tr = Tracer()
-    with tr.span("prep.pack", t=0):
-        pass
-    tr.instant("ctl.slots", round=0)
-    tr.counter("cache_hit_rate", 0.5)
-    tr.add_span("exec.sync", 10.0, 0.25, lane="worker1", wid=1)
-    path = str(tmp_path / "trace.json")
-    assert write_trace(path, tr.snapshot()) == path
-    doc = json.load(open(path))
-    evs = doc["traceEvents"]
-    metas = [e for e in evs if e["ph"] == "M"]
-    assert metas[0] == {"ph": "M", "name": "process_name", "pid": 0,
-                        "tid": 0, "args": {"name": "pollen-engine"}}
-    lanes = {e["args"]["name"]: e["tid"] for e in metas[1:]}
-    assert "worker1" in lanes and len(lanes) == 2
-    spans = [e for e in evs if e["ph"] == "X"]
-    assert all(e["ts"] >= 0 and e["dur"] >= 0 for e in spans)
-    assert {e["name"] for e in spans} == {"prep.pack", "exec.sync"}
-    sync = next(e for e in spans if e["name"] == "exec.sync")
-    assert sync["tid"] == lanes["worker1"]
-    assert sync["dur"] == pytest.approx(0.25e6)     # µs
-    insts = [e for e in evs if e["ph"] == "i"]
-    assert insts and all(e["s"] == "t" for e in insts)
-    ctrs = [e for e in evs if e["ph"] == "C"]
-    assert ctrs and ctrs[0]["args"]["value"] == 0.5
-    # empty snapshots still produce a loadable document
-    assert trace_events([])[0]["name"] == "process_name"
-
-
 # -- the tentpole invariant ---------------------------------------------------
 
 def test_tracer_on_off_bit_identity_matrix():
@@ -196,6 +173,8 @@ def test_tracer_on_off_bit_identity_matrix():
             assert st["spans"] > 0, f"no spans recorded at {tag}"
             names = {r[1] for r in obs.tracer.snapshot()}
             assert "prep.pack" in names and "exec.wait" in names, names
+            assert "wait.generator" in names, names
+            assert ("exec.wait_prep" in names) == (depth > 0), names
             if mesh:
                 assert "exec.sync" in names, names
                 sync_lanes = {r[4] for r in obs.tracer.snapshot()
@@ -223,6 +202,111 @@ def test_traced_engine_produces_producer_lane_spans():
     # every steady-state prep lands on the producer lane
     assert by_lane[main].count("prep.pack") == 1
     assert by_lane[pack_lanes[0]].count("prep.pack") == 3
+
+
+def _nested(inner, outer, eps=1e-9):
+    """``inner`` ran inside ``outer``, one level down on the same lane."""
+    return (inner[4] == outer[4] and inner[5] == outer[5] + 1
+            and inner[2] >= outer[2]
+            and inner[2] + inner[3] <= outer[2] + outer[3] + eps)
+
+
+@pytest.mark.parametrize("mesh,cache", [(0, 0), (0, 64), (2, 0), (2, 64)],
+                         ids=["fused", "device-cache", "mesh", "mesh-cache"])
+def test_traced_pipeline_books_generator_and_prep_waits(mesh, cache):
+    """Every packing path books its blocking fetch of the generator's
+    output as ``wait.generator``, nested in that round's ``prep.pack`` on
+    the producer's lane (round 0 is primed inline on the main thread); the
+    consumer books each wait for the next round's prep as
+    ``exec.wait_prep`` on the main thread, after the round it follows."""
+    obs = make_observability(trace_rounds=16)
+    _engine(mesh=mesh, depth=1, cache=cache, obs=obs).run(4)
+    recs = [r for r in obs.tracer.snapshot() if r[0] == "X"]
+    main = threading.current_thread().name
+    packs = {r[6]["t"]: r for r in recs if r[1] == "prep.pack"}
+    gens = [r for r in recs if r[1] == "wait.generator"]
+    for g in gens:
+        assert _nested(g, packs[g[6]["t"]]), (g, packs[g[6]["t"]])
+        assert g[4] == (main if g[6]["t"] == 0 else "pollen-pack_0"), g
+    rounds = sorted(g[6]["t"] for g in gens)
+    if cache:
+        # a round whose content the cache serves whole fetches nothing
+        assert rounds[0] == 0 and set(rounds) <= {0, 1, 2, 3}, rounds
+    else:
+        assert rounds == [0, 1, 2, 3], rounds
+    waits = {r[6]["t"]: r for r in recs if r[1] == "exec.wait"}
+    preps = [r for r in recs if r[1] == "exec.wait_prep"]
+    assert sorted(r[6]["t"] for r in preps) == [1, 2, 3]
+    for r in preps:
+        assert r[4] == main and r[5] == 0, r
+        before = waits[r[6]["t"] - 1]
+        assert r[2] >= before[2] + before[3] - 1e-9, (r, before)
+
+
+def _host_event_names(directory) -> set:
+    path = next(Path(directory).rglob("*.xplane.pb"))
+    return {e.name for pl in ProfileData.from_file(str(path)).planes
+            if pl.name.startswith("/host:")
+            for ln in pl.lines for e in ln.events}
+
+
+ENGINE_SPANS = {"prep.pack", "prep.refit", "wait.generator", "exec.dispatch",
+                "exec.wait", "exec.wait_prep"}
+
+
+def test_profiler_trace_holds_the_engine_spans(tmp_path):
+    obs = make_observability(trace_rounds=16)
+    eng = _engine(depth=1, obs=obs)
+    eng.run(1)                                   # compile outside the trace
+    with profiler_trace(str(tmp_path)):
+        eng.run(3)
+    assert ENGINE_SPANS <= _host_event_names(tmp_path)
+
+
+def test_untraced_engine_adds_nothing_to_the_profiler_trace(tmp_path):
+    eng = _engine(depth=1)
+    eng.run(1)
+    with profiler_trace(str(tmp_path)):
+        eng.run(2)
+    assert not ENGINE_SPANS & _host_event_names(tmp_path)
+
+
+def test_live_spans_reach_the_profiler_under_their_bare_name(tmp_path):
+    """A span enters the profiler as its name alone, so one phase groups
+    across rounds; retroactive spans, instants and counters stay in the
+    ring (the profiler cannot stamp them after the fact)."""
+    tr = Tracer()
+    with profiler_trace(str(tmp_path)):
+        with tr.span("unit.live", t=3):
+            with tr.span("unit.inner"):
+                pass
+        tr.add_span("unit.retro", 0.0, 1.0, lane="worker0")
+        tr.instant("unit.instant")
+        tr.counter("unit.counter", 1.0)
+    names = _host_event_names(tmp_path)
+    assert {"unit.live", "unit.inner"} <= names
+    assert not {n for n in names if n.startswith("unit.")} - \
+        {"unit.live", "unit.inner"}
+    assert {r[1] for r in tr.snapshot()} == {
+        "unit.live", "unit.inner", "unit.retro", "unit.instant",
+        "unit.counter"}
+
+
+def test_cli_trace_out_writes_a_profiler_trace(tmp_path):
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"),
+               PYTHONPATH=os.pathsep.join(
+                   [str(CHECKOUT / "src"), os.environ.get("PYTHONPATH", "")]))
+    out = subprocess.run(
+        [sys.executable, "-m", "repro.launch.train", "--task", "sr",
+         "--rounds", "2", "--cohort", "2", "--workers", "1",
+         "--concurrency", "1", "--steps-cap", "1",
+         "--trace-out", str(tmp_path / "trace")],
+        env=env, capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-2000:]
+    run = next((tmp_path / "trace").rglob("*.xplane.pb")).parent
+    assert (run / "perfetto_trace.json.gz").is_file()
+    assert ENGINE_SPANS <= _host_event_names(run)
 
 
 def test_round_results_report_idle_fraction_and_critical_path():

@@ -62,14 +62,16 @@ ARCHITECTURE_NEEDLES = (
 
 # What docs/OBSERVABILITY.md must keep covering: the tracer's ring
 # mechanics and the no-perturbation invariant, the span taxonomy, the
-# idle-gap formula, the Perfetto workflow, the flight-recorder dump
-# triggers, and the overhead/trend gates.
+# spans' mirror on the profiler's clock, the idle-gap formula, the
+# profiler-trace workflow, the flight-recorder dump triggers, and the
+# overhead/trend gates.
 OBSERVABILITY_NEEDLES = (
     "Tracer", "MetricsRegistry", "FlightRecorder", "make_observability",
     "NULL_TRACER", "bit-identical", "overwrite-oldest",
     "prep.pack", "prep.barrier", "exec.wait", "exec.sync", "pollen-pack",
+    "wait.generator", "exec.wait_prep", "TraceAnnotation", "add_span",
     "critique_round", "idle_time / (makespan * n_workers)",
-    "critical_path", "write_trace", "ui.perfetto.dev", "--trace-out",
+    "critical_path", "ProfileData", "ui.perfetto.dev", "--trace-out",
     "--flight-rounds", "SIGTERM", "never to raise",
     "tracer_overhead_fraction", "trend_summary.json",
     "state_dict", ".aux.npz", "exec.host_merge",
