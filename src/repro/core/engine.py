@@ -235,6 +235,8 @@ class RoundResult:
     #                                (the online pool could not fill it)
     online_pool: float = 0.0       # expected online-pool size at sample time
     #                                (0 for closed-registry samplers)
+    capped_clients: int = 0        # cohort clients whose batch count exceeds
+    #                                steps_cap (placed by the capped count)
     # -- round critique (repro.obs; see docs/OBSERVABILITY.md) -------------
     idle_fraction: float = 0.0     # simulated worker-seconds left idle:
     #                                idle_time / (makespan * n_workers) —
@@ -456,6 +458,7 @@ class _PreparedRound:
     slo_p99: float = 0.0
     stale_fraction: float = 0.0
     online_pool: float = 0.0
+    capped_clients: int = 0  # cohort clients above steps_cap
 
 
 class FederatedEngine:
@@ -807,8 +810,23 @@ class FederatedEngine:
         return [self._client_info(int(c)) for c in ids]
 
     def _client_info(self, cid: int) -> ClientInfo:
-        return ClientInfo(cid=cid, n_batches=self.dataset.n_batches(cid),
+        """The client as placement sees it: ``n_batches`` is the number of
+        local steps it trains this round (its batch count under
+        ``steps_cap``), so LB's time model, the telemetry rows, the
+        deadline trim and ``plan_round`` all time the same work.  The
+        FedAvg weight stays the sample count."""
+        steps = self.dataset.n_batches(cid)
+        if self.cfg.steps_cap is not None:
+            steps = min(steps, self.cfg.steps_cap)
+        return ClientInfo(cid=cid, n_batches=steps,
                           n_samples=self.dataset.n_samples(cid))
+
+    def _n_capped(self, clients: list[ClientInfo]) -> int:
+        """Cohort clients whose batch count exceeds ``steps_cap``."""
+        cap = self.cfg.steps_cap
+        if cap is None:
+            return 0
+        return sum(1 for c in clients if self.dataset.n_batches(c.cid) > cap)
 
     def _accumulate_loads(self, assignment: Assignment, workers, time_fn
                           ) -> tuple[float, float, list, dict]:
@@ -922,6 +940,7 @@ class FederatedEngine:
                 self.placement.refit(t)
         with tr.span("prep.sample", t=t):
             clients = self._cohort(t)
+            capped = self._n_capped(clients)
         sampler_st = sampler_state(self.sampler)
         place = (ctl.fallback_placement
                  if (fallback and ctl is not None) else self.placement)
@@ -1065,7 +1084,8 @@ class FederatedEngine:
                                   padded_steps=padded,
                                   slo_p50=slo_p50, slo_p99=slo_p99,
                                   stale_fraction=stale_fraction,
-                                  online_pool=online_pool)
+                                  online_pool=online_pool,
+                                  capped_clients=capped)
         with tr.span("prep.pack", t=t):
             if self._device_cache is not None:
                 # Cache path: no full-size host batch buffer exists at all
@@ -1111,7 +1131,8 @@ class FederatedEngine:
                                             - plan.n_steps_total),
                               slo_p50=slo_p50, slo_p99=slo_p99,
                               stale_fraction=stale_fraction,
-                              online_pool=online_pool)
+                              online_pool=online_pool,
+                              capped_clients=capped)
 
     def _pack_worker_programs(self, t, plan, worker_S, arrays, assignment,
                               workers, mesh_map, loads, fetch):
@@ -1535,7 +1556,8 @@ class FederatedEngine:
             residual_norm=prep.residual_norm,
             slo_p50=prep.slo_p50, slo_p99=prep.slo_p99,
             stale_fraction=prep.stale_fraction,
-            online_pool=prep.online_pool)
+            online_pool=prep.online_pool,
+            capped_clients=prep.capped_clients)
         # Round critique (repro.obs): idle_fraction comes from the
         # deterministic placement simulation, so it is bit-identical across
         # depths and tracer on/off; critical_path is timing-derived (like
@@ -1558,6 +1580,7 @@ class FederatedEngine:
         if tr.enabled:
             tr.counter("cache_hit_rate", hit_rate)
             tr.counter("online_pool", prep.online_pool)
+            tr.counter("capped_clients", float(prep.capped_clients))
             tr.counter("combine_bytes", float(prep.combine_bytes))
         if self._metrics is not None:
             m = self._metrics

@@ -47,7 +47,9 @@ class ClientInfo:
     """What the server knows about a sampled client before training it."""
 
     cid: int
-    n_batches: int          # x in the paper — the placement feature
+    n_batches: int          # x in the paper — the placement feature: the
+    #                         local steps trained this round (batches,
+    #                         capped by the engine's steps_cap)
     n_samples: int = 0      # aggregation weight n_k (defaults to batches)
 
     @property

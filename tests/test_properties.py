@@ -83,6 +83,9 @@ def _normalise(depth, bucket, mesh, compress, hosts):
         combine = "flat"
         family = ("flat",)
         ref = dict(mesh=0)
+        if bucket == "worker":
+            # Per-worker S buckets exist only on the mesh path.
+            mesh = max(mesh, 2)
     cfg = dict(depth=depth, bucket=bucket, mesh=mesh, combine=combine,
                compress=compress, hosts=hosts)
     return cfg, family, ref

@@ -58,6 +58,8 @@ ARCHITECTURE_NEEDLES = (
     "Host hierarchy", "HostShardMap", "pairwise_reduce",
     "launch.multihost", "SidecarChannel", "host_layout",
     "exec.host_merge", "O(hosts)",
+    # the placement feature: the steps a client trains this round
+    "The placement feature", "capped_clients",
 )
 
 # What docs/OBSERVABILITY.md must keep covering: the tracer's ring
@@ -74,7 +76,7 @@ OBSERVABILITY_NEEDLES = (
     "critical_path", "ProfileData", "ui.perfetto.dev", "--trace-out",
     "--flight-rounds", "SIGTERM", "never to raise",
     "tracer_overhead_fraction", "trend_summary.json",
-    "state_dict", ".aux.npz", "exec.host_merge",
+    "state_dict", ".aux.npz", "exec.host_merge", "capped_clients",
 )
 
 # What docs/POPULATION.md must keep covering: the registry's hash streams,
