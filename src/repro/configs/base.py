@@ -32,6 +32,7 @@ class ArchConfig:
     qk_norm: bool = False
     use_bias: bool = False
     tie_embeddings: bool = False
+    vocab_multiple: int = 256   # embed/lm_head rows padded to a multiple
     rope_theta: float = 10_000.0
     norm_eps: float = 1e-6
 
@@ -43,6 +44,23 @@ class ArchConfig:
     moe_every: int = 1          # apply MoE on layers where l % moe_every == moe_offset
     moe_offset: int = 0
     capacity_factor: float = 1.25
+    # DeepSeek-V3-style routing (dropless expert layer, models/layers.py):
+    # 'softmax' routes through the capacity path above; 'sigmoid' scores
+    # each expert alone, picks the top-k scores (the correction bias held
+    # at 0: no parameter, not trained), renormalises them and scales them
+    # by moe_routed_scale; no aux loss.
+    moe_score: str = "softmax"
+    moe_routed_scale: float = 1.0
+    moe_shared_d_ff: int = 0    # shared experts as one SwiGLU of this width
+    experts_held: int = 0       # experts 0..held-1 live here (0 -> all)
+    first_dense_layers: int = 0  # leading dense layers ahead of the period
+
+    # multi-head latent attention (MLA, mixer 'mla' when kv_lora_rank > 0;
+    # RoPE over interleaved pairs)
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # hybrid / ssm
     attn_every: int = 1         # jamba: attention on layers where l % attn_every == attn_offset
@@ -118,6 +136,20 @@ class ArchConfig:
         return l % self.moe_every == self.moe_offset
 
     @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def n_experts_held(self) -> int:
+        return self.experts_held or self.n_experts
+
+    @property
+    def servable(self) -> bool:
+        """Has a serving cache (``init_cache``/``prefill``/``decode_step``):
+        none for latent attention or leading dense layers."""
+        return not (self.mla or self.first_dense_layers)
+
+    @property
     def sub_quadratic(self) -> bool:
         """Can this arch run long_500k? (SSM / hybrid / linear-attn.)"""
         return self.family in ("ssm", "hybrid")
@@ -132,10 +164,11 @@ class ArchConfig:
 
     @property
     def padded_vocab(self) -> int:
-        """Vocab rounded to a 256 multiple so embed/lm_head shard evenly
-        over a 16-way TP axis with 128-lane alignment (Megatron-style
+        """Vocab rounded to a ``vocab_multiple`` (256: embed/lm_head shard
+        evenly over a 16-way TP axis with 128-lane alignment, Megatron-style
         padding; pad columns are masked out of the loss)."""
-        return (self.vocab_size + 255) // 256 * 256
+        m = self.vocab_multiple
+        return (self.vocab_size + m - 1) // m * m
 
     def encoder_cfg(self) -> "ArchConfig":
         """The encoder stack of an enc-dec arch as a standalone config:
@@ -162,6 +195,18 @@ class ArchConfig:
             kw["n_experts"] = min(self.n_experts, 4)
             kw["top_k"] = min(self.top_k, 2)
             kw["moe_d_ff"] = min(self.moe_d_ff, 64)
+            if self.experts_held:
+                kw["experts_held"] = min(self.experts_held, kw["n_experts"])
+            if self.moe_shared_d_ff:
+                kw["moe_shared_d_ff"] = min(self.moe_shared_d_ff, 128)
+        if self.mla:
+            kw["kv_lora_rank"] = min(self.kv_lora_rank, 32)
+            kw["qk_nope_head_dim"] = min(self.qk_nope_head_dim, 16)
+            kw["qk_rope_head_dim"] = min(self.qk_rope_head_dim, 8)
+            kw["v_head_dim"] = min(self.v_head_dim, 16)
+        if self.first_dense_layers:
+            kw["n_layers"] = self.first_dense_layers + max(
+                1, min(self.n_layers - self.first_dense_layers, 3))
         if self.ssm_state:
             kw["ssm_state"] = min(self.ssm_state, 16)
             kw["ssm_head_dim"] = 16

@@ -237,6 +237,11 @@ class RoundResult:
     #                                (0 for closed-registry samplers)
     capped_clients: int = 0        # cohort clients whose batch count exceeds
     #                                steps_cap (placed by the capped count)
+    moe_routed_rows: int = 0       # dropless expert layer, summed over the
+    #                                round's real steps and its layers:
+    #                                token-rows routed to the held experts
+    moe_buffer_rows: int = 0       # rows the held experts' grouped
+    #                                products are given (fused step only)
     # -- round critique (repro.obs; see docs/OBSERVABILITY.md) -------------
     idle_fraction: float = 0.0     # simulated worker-seconds left idle:
     #                                idle_time / (makespan * n_workers) —
@@ -1523,7 +1528,9 @@ class FederatedEngine:
         """Consumer tail: result bookkeeping and periodic checkpoint.  (The
         time-model refit AND telemetry recording live in ``_prepare_round``.)"""
         t = prep.t
-        loss = float(metrics.loss)             # device sync point
+        # Device sync point; the model's counts come back in the same fetch.
+        loss, counts = jax.device_get((metrics.loss, metrics.counts or {}))
+        loss = float(loss)
         stats = padding_stats(prep.arrays)
         cp = prep.cache_plan
         hit_rate = cp.hit_rate if cp is not None else 0.0
@@ -1557,7 +1564,9 @@ class FederatedEngine:
             slo_p50=prep.slo_p50, slo_p99=prep.slo_p99,
             stale_fraction=prep.stale_fraction,
             online_pool=prep.online_pool,
-            capped_clients=prep.capped_clients)
+            capped_clients=prep.capped_clients,
+            moe_routed_rows=int(counts.get("moe_routed_rows", 0)),
+            moe_buffer_rows=int(counts.get("moe_buffer_rows", 0)))
         # Round critique (repro.obs): idle_fraction comes from the
         # deterministic placement simulation, so it is bit-identical across
         # depths and tracer on/off; critical_path is timing-derived (like
@@ -1582,6 +1591,9 @@ class FederatedEngine:
             tr.counter("online_pool", prep.online_pool)
             tr.counter("capped_clients", float(prep.capped_clients))
             tr.counter("combine_bytes", float(prep.combine_bytes))
+            for name in ("moe_routed_rows", "moe_buffer_rows"):
+                if name in counts:
+                    tr.counter(name, float(counts[name]), t=t)
         if self._metrics is not None:
             m = self._metrics
             m.inc("rounds")

@@ -62,6 +62,12 @@ TASK_DISTRIBUTIONS: dict[str, TaskSpec] = {
                    size_max=4_000, n_classes=35),
     "mlm": TaskSpec(name="mlm", kind="tokens", n_clients=1_600_000, batch_size=20,
                     size_dist="zipf", zipf_a=1.35, size_max=60_000, n_classes=0),
+    # kanana2-a3b: the MLM population, 4 sequences a batch (the vocabulary
+    # slice and the sequence length are the task's, set by build_engine).
+    "kanana2-a3b": TaskSpec(name="kanana2-a3b", kind="tokens",
+                            n_clients=1_600_000, batch_size=4,
+                            size_dist="zipf", zipf_a=1.35, size_max=60_000,
+                            n_classes=0),
     # LM-architecture FL tasks (the assigned archs trained federatedly).
     "lm": TaskSpec(name="lm", kind="tokens", n_clients=100_000, batch_size=8,
                    size_dist="lognormal", size_mu=4.5, size_sigma=1.3,

@@ -78,6 +78,21 @@ class RoundMetrics(NamedTuple):
     steps: Any           # number of real local steps executed
     clients: Any         # number of clients folded
     total_weight: Any    # sum of aggregation weights
+    counts: Any = None   # the model's per-step counts (a loss_fn that
+    #                      returns (loss, counts)) summed over the round's
+    #                      real steps; fused round step only
+
+
+def _with_counts(loss_fn):
+    """``loss_fn`` may return a scalar loss or ``(loss, counts)``, a dict
+    of scalar counts (the dropless expert layer's row counts); the round
+    programs take ``(loss, counts)`` either way."""
+
+    def f(params, batch):
+        out = loss_fn(params, batch)
+        return out if isinstance(out, tuple) else (out, {})
+
+    return f
 
 
 def _tree_select(flag, a, b):
@@ -92,6 +107,8 @@ def _make_lane_scan(loss_fn, optimizer, *, agg_impl: str = "xla",
     the fused round step and the per-worker mesh programs — the per-lane
     math is what the decomposition invariance rests on."""
 
+    grad_fn = jax.value_and_grad(_with_counts(loss_fn), has_aux=True)
+
     def lane_scan(global_params, lane_batches, mask, boundary, weight):
         opt0 = optimizer.init(global_params)
         partial0 = partial_init(global_params)
@@ -99,7 +116,7 @@ def _make_lane_scan(loss_fn, optimizer, *, agg_impl: str = "xla",
         def step(carry, inp):
             theta, opt_state, partial, loss_sum = carry
             batch, m, bnd, w = inp
-            loss, grads = jax.value_and_grad(loss_fn)(theta, batch)
+            (loss, counts), grads = grad_fn(theta, batch)
             if grad_clip is not None:
                 from repro.optim.optimizers import clip_by_global_norm
                 grads, _ = clip_by_global_norm(grads, grad_clip)
@@ -130,12 +147,14 @@ def _make_lane_scan(loss_fn, optimizer, *, agg_impl: str = "xla",
             # program that embeds this scan — an XLA reduce over the
             # per-step losses instead may tile (and round) differently in
             # the fused round step vs the mesh path's combine program.
-            return (theta, opt_state, partial, loss_sum + loss * m), None
+            # Counts are integer-valued, so their sum is exact in any order.
+            return ((theta, opt_state, partial, loss_sum + loss * m),
+                    jax.tree.map(lambda c: c * m, counts))
 
-        (_, _, partial, loss_sum), _ = jax.lax.scan(
+        (_, _, partial, loss_sum), counts = jax.lax.scan(
             step, (global_params, opt0, partial0, jnp.zeros(())),
             (lane_batches, mask, boundary, weight))
-        return partial, loss_sum
+        return partial, loss_sum, jax.tree.map(jnp.sum, counts)
 
     return lane_scan
 
@@ -164,9 +183,9 @@ def make_round_step(loss_fn, optimizer, *, agg_impl: str = "xla",
             # single-worker fast path: no vmap wrappers, so manual-collective
             # layers (shard_map EP dispatch, §Perf B3) can live inside.
             squeezed = jax.tree.map(lambda x: x[0, 0], batches)
-            partial, loss1 = lane_scan(global_params, squeezed,
-                                       step_mask[0, 0], boundary[0, 0],
-                                       weight[0, 0])
+            partial, loss1, counts = lane_scan(global_params, squeezed,
+                                               step_mask[0, 0], boundary[0, 0],
+                                               weight[0, 0])
             partials = jax.tree.map(lambda x: x[None, None], partial)
             lane_losses = loss1[None, None]
         else:
@@ -175,11 +194,14 @@ def make_round_step(loss_fn, optimizer, *, agg_impl: str = "xla",
             per_lane = jax.vmap(lane_scan, in_axes=(None, 0, 0, 0, 0))
             per_worker = jax.vmap(per_lane, in_axes=(None, 0, 0, 0, 0),
                                   spmd_axis_name=worker_spmd_axes)
-            partials, lane_losses = per_worker(global_params, batches,
-                                               step_mask, boundary, weight)
+            partials, lane_losses, counts = per_worker(
+                global_params, batches, step_mask, boundary, weight)
+            counts = jax.tree.map(jnp.sum, counts)
         theta_wp, n_wp = partials                     # leaves [W,P,...], [W,P]
-        return _reduce_partials(global_params, theta_wp, n_wp, lane_losses,
-                                step_mask, boundary, weight)
+        new_global, metrics = _reduce_partials(
+            global_params, theta_wp, n_wp, lane_losses, step_mask, boundary,
+            weight)
+        return new_global, metrics._replace(counts=counts)
 
     return round_step
 
@@ -251,8 +273,8 @@ def make_worker_round_step(loss_fn, optimizer, *, agg_impl: str = "xla",
         # bit-identical for every multi-worker round.
         per_lane = jax.vmap(lane_scan, in_axes=(None, 0, 0, 0, 0))
         per_worker = jax.vmap(per_lane, in_axes=(None, 0, 0, 0, 0))
-        partials, lane_losses = per_worker(global_params, batches, step_mask,
-                                           boundary, weight)
+        partials, lane_losses, _ = per_worker(global_params, batches,
+                                              step_mask, boundary, weight)
         theta_wp, n_wp = partials
         return theta_wp, n_wp, lane_losses
 
@@ -477,13 +499,15 @@ def make_gather_round_step(loss_fn, optimizer, *, grad_clip: float | None = None
     weights [W*P], metrics)``; the caller applies the strategy's reduce.
     """
 
+    grad_fn = jax.value_and_grad(_with_counts(loss_fn), has_aux=True)
+
     def lane_scan(global_params, lane_batches, mask, boundary, weight):
         opt0 = optimizer.init(global_params)
 
         def step(carry, inp):
             theta, opt_state = carry
             batch, m = inp
-            loss, grads = jax.value_and_grad(loss_fn)(theta, batch)
+            (loss, _), grads = grad_fn(theta, batch)
             if grad_clip is not None:
                 from repro.optim.optimizers import clip_by_global_norm
                 grads, _ = clip_by_global_norm(grads, grad_clip)

@@ -53,6 +53,9 @@ def skip_reason(cfg: ArchConfig, shape_name: str) -> str | None:
     if shape_name == "long_500k" and not cfg.sub_quadratic:
         return ("full quadratic attention at 524288 tokens — skipped per "
                 "assignment; runs only for ssm/hybrid families")
+    if SHAPES[shape_name].is_serve and not cfg.servable:
+        return ("no serving cache for latent attention or leading dense "
+                "layers — the model is trained, not served")
     return None
 
 

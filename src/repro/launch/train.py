@@ -41,7 +41,7 @@ from repro.models.papertasks import TASK_MODELS, make_task_model
 from repro.obs import make_observability, profiler_trace
 from repro.optim import adam, sgd
 
-__all__ = ["build_engine", "main", "flags_markdown", "PRESETS"]
+__all__ = ["build_engine", "main", "flags_markdown", "PRESETS", "TASK_CLIENTS"]
 
 # LM presets for the CPU driver ("smoke" for tests/examples; "fl100m" is the
 # ~100M-param end-to-end config for real runs).
@@ -52,6 +52,24 @@ PRESETS = {
     "fl100m": dict(n_layers=12, d_model=768, n_heads=12, n_kv_heads=4,
                    head_dim=64, d_ff=2048, vocab_size=32_000, seq_len=256,
                    batch_size=8),
+}
+
+
+def _kanana_data() -> dict:
+    from repro.configs import kanana_2_30b_a3b as share
+    return dict(vocab_size=share.SHARE.vocab_size, seq_len=share.SEQ_LEN)
+
+
+# Per-task client setup: the client optimizer (paper A.1: SGD with momentum
+# and weight decay for IC/SR/TG, Adam for MLM; FedAvg's ClientUpdate, plain
+# SGD, for kanana2-a3b) and, where the task's TaskSpec does not fix them,
+# the dataset's vocabulary and sequence length.
+TASK_CLIENTS = {
+    "ic": dict(optimizer=lambda: sgd(0.05, momentum=0.9, weight_decay=5e-4)),
+    "sr": dict(optimizer=lambda: sgd(0.05, momentum=0.9, weight_decay=5e-4)),
+    "tg": dict(optimizer=lambda: sgd(0.8, momentum=0.9, weight_decay=5e-4)),
+    "mlm": dict(optimizer=lambda: adam(4e-5)),
+    "kanana2-a3b": dict(optimizer=lambda: sgd(0.01), data=_kanana_data),
 }
 
 
@@ -186,13 +204,12 @@ def build_engine(*, task: str | None = None, arch: str | None = None,
         batch_kw = dict(batch_size=batch_size, seq_len=seq_len)
     else:
         task = task or "sr"
+        client = TASK_CLIENTS[task]
         params, loss_fn = make_task_model(task, key)
         ds = make_federated_dataset(
-            task, seed=seed,
+            task, seed=seed, **client.get("data", dict)(),
             **({"n_clients": base_clients} if base_clients else {}))
-        optimizer = adam(4e-5) if task == "mlm" else sgd(
-            0.05 if task != "tg" else 0.8, momentum=0.9,
-            weight_decay=5e-4 if task != "mlm" else 0.0)
+        optimizer = client["optimizer"]()
         batch_kw = dict(batch_size=ds.spec.batch_size)
 
     pool = (WorkerPool.from_specs(worker_specs) if worker_specs

@@ -3,18 +3,22 @@ assigned families (dense / moe / vlm / hybrid / audio / ssm), plus the
 paper's four FL-task models (repro.models.papertasks)."""
 
 from repro.models.lm import (decode_step, forward, init_cache, init_params,
-                             layer_plan, loss_fn, param_count, prefill)
+                             layer_plan, loss_and_counts, loss_fn,
+                             param_count, prefill)
 
-__all__ = ["init_params", "forward", "loss_fn", "init_cache", "prefill",
+__all__ = ["init_params", "forward", "loss_fn", "loss_and_counts",
+           "init_cache", "prefill",
            "decode_step", "layer_plan", "param_count", "make_loss_fn",
            "make_batch_spec"]
 
 
 def make_loss_fn(cfg):
-    """Bind the arch config: loss(params, batch) for the FL engine."""
+    """Bind the arch config: ``loss(params, batch) -> (loss, counts)`` for
+    the FL engine's round programs, ``counts`` the model's per-step counts
+    (the dropless expert layer's rows; empty for most architectures)."""
 
     def _loss(params, batch):
-        return loss_fn(params, batch, cfg)
+        return loss_and_counts(params, batch, cfg)
 
     return _loss
 

@@ -23,7 +23,9 @@ import jax
 import jax.numpy as jnp
 
 __all__ = [
-    "rms_norm", "rope", "gqa_attention", "swiglu", "gelu_mlp", "moe_layer",
+    "rms_norm", "rope", "rope_interleaved", "gqa_attention", "mla_attention",
+    "swiglu", "gelu_mlp", "moe_layer", "grouped_matmul", "route_topk",
+    "moe_dropless",
     "dense_init", "norm_init", "causal_scores_mask", "decode_attention",
 ]
 
@@ -80,6 +82,16 @@ def rope(x, positions, *, theta: float = 10_000.0):
     return out.astype(x.dtype)
 
 
+def rope_interleaved(x, positions, *, theta: float = 10_000.0):
+    """RoPE over interleaved pairs ``(x0, x1), (x2, x3), ...`` (DeepSeek-V3's
+    ``rope_interleave``): de-interleave to ``[x0, x2, ..., x1, x3, ...]``,
+    then rotate halves.  The output keeps the de-interleaved order; applied
+    to q and k alike it leaves every q·k product as the pairwise rotation
+    gives it."""
+    x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+    return rope(x, positions, theta=theta)
+
+
 # --------------------------------------------------------------------------
 # attention
 # --------------------------------------------------------------------------
@@ -104,7 +116,7 @@ def _dense_attention(q, k, v, *, causal: bool, q_offset: int = 0):
         scores = causal_scores_mask(scores, q_pos, k_pos)
     probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
     out = jnp.einsum("bkgst,btkd->bskgd", probs, v)
-    return out.reshape(b, s, hq, hd)
+    return out.reshape(b, s, hq, v.shape[-1])
 
 
 def _chunked_attention(q, k, v, *, causal: bool, q_chunk: int = 512,
@@ -148,7 +160,8 @@ def _chunked_attention(q, k, v, *, causal: bool, q_chunk: int = 512,
 
     _, outs = jax.lax.scan(chunk_fn, (),
                            (jnp.moveaxis(qs, 1, 0), jnp.arange(n_chunks)))
-    out = jnp.moveaxis(outs, 0, 1).reshape(b, n_chunks * q_chunk, hq, hd)
+    out = jnp.moveaxis(outs, 0, 1).reshape(b, n_chunks * q_chunk, hq,
+                                           v.shape[-1])
     return out[:, :s]
 
 
@@ -164,6 +177,34 @@ def gqa_attention(q, k, v, *, causal: bool = True, impl: str = "dense",
         from repro.kernels import ops as kops
         return kops.flash_attention(q, k, v, causal=causal)
     raise ValueError(f"unknown attention impl {impl!r}")
+
+
+def mla_attention(p, h, positions, *, n_heads: int, nope: int, rope_dim: int,
+                  v_dim: int, rank: int, theta: float, eps: float,
+                  causal: bool = True, impl: str = "dense", q_chunk: int = 512):
+    """Multi-head latent attention without a query LoRA (DeepSeek-V2/V3).
+
+    h [b, s, D] (normed input).  Per head, q = [q_nope | q_rope] from
+    ``wq``; the keys and values come from a shared latent: ``wkv_a`` gives
+    ``[c | k_rope]`` (rank + rope_dim), ``c`` passes its own RMS norm
+    (``kv_norm``) and ``wkv_b`` expands it to per-head ``[k_nope | v]``;
+    ``k_rope`` is one head shared by all heads; RoPE rotates interleaved
+    pairs (``rope_interleaved``).  Scores are scaled
+    by ``(nope + rope_dim) ** -0.5``.  Returns [b, s, n_heads * v_dim]."""
+    b, s, _ = h.shape
+    q = (h @ p["wq"]).reshape(b, s, n_heads, nope + rope_dim)
+    kv = h @ p["wkv_a"]
+    c, k_rot = kv[..., :rank], kv[..., rank:]
+    kvb = (rms_norm(c, p["kv_norm"], eps=eps) @ p["wkv_b"]).reshape(
+        b, s, n_heads, nope + v_dim)
+    k_nope, v = kvb[..., :nope], kvb[..., nope:]
+    q_rot = rope_interleaved(q[..., nope:], positions, theta=theta)
+    k_rot = rope_interleaved(k_rot[:, :, None, :], positions, theta=theta)
+    q = jnp.concatenate([q[..., :nope], q_rot], axis=-1)
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_rot, (b, s, n_heads, rope_dim))], axis=-1)
+    out = gqa_attention(q, k, v, causal=causal, impl=impl, q_chunk=q_chunk)
+    return out.reshape(b, s, n_heads * v_dim)
 
 
 def decode_attention(q, k_cache, v_cache, kv_len_mask):
@@ -270,6 +311,116 @@ def moe_layer_3d(x3, router_w, moe_gate, moe_up, moe_down, *, top_k: int,
     aux, outs = jax.lax.scan(fn, jnp.zeros((), jnp.float32), xs)
     out = jnp.moveaxis(outs, 0, 1).reshape(b, nc * seq_chunk, D)[:, :s]
     return out, aux / nc
+
+
+_WGRAD_DIMS = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(([0], [0]), ([], [])), lhs_ragged_dimensions=[0],
+    rhs_group_dimensions=[])
+
+
+def _map_rule(fn):
+    """vmap rule: run ``fn`` once per batch element (``lax.map``).  JAX's own
+    batching of ``ragged_dot`` handles only operands all batched on axis 0
+    and fails when nested (the lane grid is a vmap over workers of a vmap
+    over lanes)."""
+
+    def rule(axis_size, in_batched, *args):
+        full = [a if b else jnp.broadcast_to(a, (axis_size,) + a.shape)
+                for a, b in zip(args, in_batched)]
+        return jax.lax.map(lambda xs: fn(*xs), tuple(full)), True
+
+    return rule
+
+
+@jax.custom_batching.custom_vmap
+def _gmm(x, w, sizes):
+    """[M, K] x [G, K, N] -> [M, N]: rows of group g times w[g], in order;
+    rows past the groups are zero.  (The TPU's grouped-matmul kernel visits
+    only the tiles that hold group rows and leaves the rest of its output
+    unwritten, so those rows are zeroed here.)"""
+    rows = jnp.arange(x.shape[0])[:, None] < jnp.sum(sizes)
+    return jnp.where(rows, jax.lax.ragged_dot(x, w, sizes), 0)
+
+
+@jax.custom_batching.custom_vmap
+def _gmm_wgrad(x, dy, sizes):
+    """[M, K], [M, N] -> [G, K, N]: per group, its rows of x^T dy (zero for
+    an empty group, which the TPU kernel leaves unwritten)."""
+    dw = jax.lax.ragged_dot_general(x, dy, sizes, _WGRAD_DIMS)
+    return jnp.where(sizes[:, None, None] > 0, dw, 0)
+
+
+_gmm.def_vmap(_map_rule(_gmm))
+_gmm_wgrad.def_vmap(_map_rule(_gmm_wgrad))
+
+
+@jax.custom_vjp
+def grouped_matmul(x, w, sizes):
+    """Grouped product over contiguous row groups (``jax.lax.ragged_dot``),
+    differentiable and batchable at any vmap depth."""
+    return _gmm(x, w, sizes)
+
+
+def _grouped_fwd(x, w, sizes):
+    return _gmm(x, w, sizes), (x, w, sizes)
+
+
+def _grouped_bwd(res, dy):
+    x, w, sizes = res
+    return (_gmm(dy, jnp.swapaxes(w, 1, 2), sizes).astype(x.dtype),
+            _gmm_wgrad(x, dy, sizes).astype(w.dtype), None)
+
+
+grouped_matmul.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+def route_topk(x, router_w, *, top_k: int, scale: float = 1.0):
+    """Sigmoid routing over every expert (DeepSeek-V3 ``noaux_tc`` with one
+    group and the correction bias at zero), in float32 at ``highest``
+    precision, so the top-k choice matches a float32 reference except at
+    true ties: the top-k sigmoid scores are chosen, renormalised to sum to
+    1 (``norm_topk_prob``) and multiplied by ``scale``.  x [T, D], router_w
+    [D, E].  Returns (weights [T, k] float32, expert ids [T, k] int32)."""
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    w, idx = jax.lax.top_k(jax.nn.sigmoid(logits), top_k)
+    return w / (w.sum(-1, keepdims=True) + 1e-20) * scale, idx
+
+
+def moe_dropless(x, router_w, moe_gate, moe_up, moe_down, *, top_k: int,
+                 scale: float = 1.0):
+    """Dropless top-k SwiGLU experts over the experts held here.
+
+    The router scores all E experts (``router_w`` [D, E]); ``moe_gate``/
+    ``moe_up`` [H, D, F] and ``moe_down`` [H, F, D] hold experts 0 .. H - 1
+    (one chip's share under expert parallelism; H == E holds them all).  Every
+    (token, choice) pair routed to a held expert is computed, none dropped:
+    the T·k pairs are sorted by expert, pairs of experts held elsewhere last,
+    and a grouped product (``grouped_matmul``) gives each held expert
+    exactly its rows; rows past the held groups are zero.  What the absent
+    experts add is left out, as on the chip that holds this share.
+
+    Returns (out [T, D], counts): ``moe_routed_rows``, the pairs routed to
+    held experts, and ``moe_buffer_rows``, the rows the grouped products
+    are given (T·k)."""
+    T, D = x.shape
+    H = moe_gate.shape[0]
+    w, idx = route_topk(x, router_w, top_k=top_k, scale=scale)
+    flat = idx.reshape(-1)
+    held = flat < H
+    group = jnp.where(held, flat, H)
+    order = jnp.argsort(group, stable=True)
+    tok = order // top_k
+    sizes = jnp.bincount(group, length=H + 1)[:H].astype(jnp.int32)
+    xs = x[tok]
+    hid = (jax.nn.silu(grouped_matmul(xs, moe_gate, sizes))
+           * grouped_matmul(xs, moe_up, sizes))
+    ys = grouped_matmul(hid, moe_down, sizes)
+    ws = jnp.where(held, w.reshape(-1), 0.0)[order].astype(ys.dtype)
+    out = jnp.zeros((T, D), ys.dtype).at[tok].add(ys * ws[:, None])
+    counts = {"moe_routed_rows": held.sum().astype(jnp.float32),
+              "moe_buffer_rows": jnp.float32(T * top_k)}
+    return out.astype(x.dtype), counts
 
 
 def _moe_dispatch(x, router_w, moe_gate, moe_up, moe_down, *, top_k: int,

@@ -9,10 +9,17 @@ period-position with a leading ``n_periods`` dim and the stack runs as a
 is what keeps the 94-layer qwen3-moe dry-run compile tractable.
 
 Block kinds (cfg-driven):
-    mixer: 'attn' (GQA + RoPE [+qk-norm] [+cross-attn]) | 'mamba' (SSD) | none
+    mixer: 'attn' (GQA + RoPE [+qk-norm] [+cross-attn]) | 'mla' (latent
+           attention, DeepSeek-V3) | 'mamba' (SSD) | none
     mlp  : 'swiglu' | 'relu2' | 'gelu' | 'moe' | none
     command-r style ``parallel_block``: shared input norm, attn+mlp outputs
     added to the residual together.
+
+Leading dense layers (``cfg.first_dense_layers``, DeepSeek-V3's
+``first_k_dense_replace``) form a stack of their own, ``params["dense"]``,
+run ahead of the periodic stack.  An MoE block with sigmoid routing runs
+the dropless expert layer (``layers.moe_dropless``) beside an optional
+shared expert, and reports its row counts instead of an auxiliary loss.
 
 Entry points:
     init_params(key, cfg)                     -> params pytree
@@ -38,10 +45,11 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig
 from repro.models import ssd as ssdlib
 from repro.models.layers import (decode_attention, dense_init, gqa_attention,
-                                 rms_norm, rope)
+                                 mla_attention, moe_dropless, rms_norm, rope)
 
-__all__ = ["init_params", "forward", "loss_fn", "init_cache", "prefill",
-           "decode_step", "layer_plan", "LayerKind", "param_count"]
+__all__ = ["init_params", "forward", "loss_fn", "loss_and_counts",
+           "init_cache", "prefill", "decode_step", "layer_plan", "LayerKind",
+           "param_count"]
 
 
 # ---------------------------------------------------------------------------
@@ -49,7 +57,7 @@ __all__ = ["init_params", "forward", "loss_fn", "init_cache", "prefill",
 # ---------------------------------------------------------------------------
 @dataclass(frozen=True)
 class LayerKind:
-    mixer: str          # 'attn' | 'mamba' | 'none'
+    mixer: str          # 'attn' | 'mla' | 'mamba' | 'none'
     mlp: str            # 'swiglu' | 'relu2' | 'gelu' | 'moe' | 'none'
     cross: bool = False # decoder cross-attention (whisper)
 
@@ -65,7 +73,7 @@ def layer_plan(cfg: ArchConfig, *, decoder: bool = True) -> list[LayerKind]:
         period = _lcm(period, cfg.attn_every)
     if cfg.moe and cfg.moe_every > 1:
         period = _lcm(period, cfg.moe_every)
-    n_layers = cfg.n_layers
+    n_layers = cfg.n_layers - cfg.first_dense_layers
     if n_layers % period:
         raise ValueError(f"{cfg.name}: n_layers {n_layers} not divisible by "
                          f"period {period}")
@@ -76,7 +84,7 @@ def layer_plan(cfg: ArchConfig, *, decoder: bool = True) -> list[LayerKind]:
         elif cfg.attn_every > 1:
             mixer = "attn" if l % cfg.attn_every == cfg.attn_offset else "mamba"
         else:
-            mixer = "attn"
+            mixer = "mla" if cfg.mla else "attn"
         if cfg.moe and l % cfg.moe_every == cfg.moe_offset:
             mlp = "moe"
         elif cfg.d_ff > 0:
@@ -86,6 +94,13 @@ def layer_plan(cfg: ArchConfig, *, decoder: bool = True) -> list[LayerKind]:
         cross = decoder and cfg.enc_layers > 0 and mixer == "attn"
         plan.append(LayerKind(mixer=mixer, mlp=mlp, cross=cross))
     return plan
+
+
+def dense_plan(cfg: ArchConfig) -> list[LayerKind]:
+    """The leading dense layers' kind (empty without any)."""
+    if not cfg.first_dense_layers:
+        return []
+    return [LayerKind(mixer="mla" if cfg.mla else "attn", mlp=cfg.mlp_act)]
 
 
 # ---------------------------------------------------------------------------
@@ -108,6 +123,19 @@ def _attn_shapes(cfg: ArchConfig) -> dict:
         sh.update({"bq": (cfg.n_heads * hd,), "bk": (cfg.n_kv_heads * hd,),
                    "bv": (cfg.n_kv_heads * hd,), "bo": (D,)})
     return sh
+
+
+def _mla_shapes(cfg: ArchConfig) -> dict:
+    D, H, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    nope, rd, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    return {
+        "attn_norm": (D,),
+        "wq": (D, H * (nope + rd)),
+        "wkv_a": (D, r + rd),
+        "kv_norm": (r,),
+        "wkv_b": (r, H * (nope + vd)),
+        "wo": (H * vd, D),
+    }
 
 
 def _cross_shapes(cfg: ArchConfig) -> dict:
@@ -136,10 +164,14 @@ def _mlp_shapes(cfg: ArchConfig, kind: str) -> dict:
         if cfg.use_bias:
             sh.update({"b_up": (F,), "b_down": (D,)})
     elif kind == "moe":
-        E, Fm = cfg.n_experts, cfg.moe_d_ff
+        E, H, Fm = cfg.n_experts, cfg.n_experts_held, cfg.moe_d_ff
         sh = {"mlp_norm": (D,), "router": (D, E),
-              "moe_gate": (E, D, Fm), "moe_up": (E, D, Fm),
-              "moe_down": (E, Fm, D)}
+              "moe_gate": (H, D, Fm), "moe_up": (H, D, Fm),
+              "moe_down": (H, Fm, D)}
+        if cfg.moe_shared_d_ff:
+            Fs = cfg.moe_shared_d_ff
+            sh.update({"shared_gate": (D, Fs), "shared_up": (D, Fs),
+                       "shared_down": (Fs, D)})
     else:
         sh = {}
     return sh
@@ -155,6 +187,8 @@ def _block_shapes(cfg: ArchConfig, kind: LayerKind) -> dict:
     sh = {}
     if kind.mixer == "attn":
         sh.update(_attn_shapes(cfg))
+    elif kind.mixer == "mla":
+        sh.update(_mla_shapes(cfg))
     elif kind.mixer == "mamba":
         sh.update(_mamba_shapes(cfg))
     if kind.cross:
@@ -200,7 +234,7 @@ def _init_stack(key, cfg: ArchConfig, plan, n_periods: int, dtype):
 def init_params(key, cfg: ArchConfig):
     dtype = jnp.dtype(cfg.dtype)
     plan = layer_plan(cfg)
-    n_periods = cfg.n_layers // len(plan)
+    n_periods = (cfg.n_layers - cfg.first_dense_layers) // len(plan)
     k_embed, k_stack, k_head, k_enc, k_extra = jax.random.split(key, 5)
     params = {
         "embed": dense_init(k_embed, (cfg.padded_vocab, cfg.d_model), dtype,
@@ -208,6 +242,10 @@ def init_params(key, cfg: ArchConfig):
         "stack": _init_stack(k_stack, cfg, plan, n_periods, dtype),
         "final_norm": jnp.ones((cfg.d_model,), jnp.float32),
     }
+    if cfg.first_dense_layers:
+        params["dense"] = _init_stack(jax.random.fold_in(k_stack, 1), cfg,
+                                      dense_plan(cfg), cfg.first_dense_layers,
+                                      dtype)
     if not cfg.tie_embeddings:
         params["lm_head"] = dense_init(k_head, (cfg.d_model,
                                                 cfg.padded_vocab), dtype)
@@ -312,11 +350,13 @@ def _encode_cross_kv(p, enc_out, cfg: ArchConfig):
 
 
 def _mlp_body(p, x, cfg: ArchConfig, kind: str, *, norm_key: str = "mlp_norm"):
+    """Returns (out, stats): ``stats`` holds the block's auxiliary loss
+    (``aux``, capacity-routed MoE) or its expert row counts (dropless)."""
     h = rms_norm(x, p[norm_key], eps=cfg.norm_eps) if norm_key else x
     h = cfg.act_gather(h)
     if kind == "swiglu":
         z = jax.nn.silu(h @ p["w_gate"]) * (h @ p["w_up"])
-        return z @ p["w_down"], 0.0
+        return z @ p["w_down"], {}
     if kind in ("relu2", "gelu"):
         z = h @ p["w_up"]
         if cfg.use_bias:
@@ -325,19 +365,30 @@ def _mlp_body(p, x, cfg: ArchConfig, kind: str, *, norm_key: str = "mlp_norm"):
         out = z @ p["w_down"]
         if cfg.use_bias:
             out = out + p["b_down"]
-        return out, 0.0
+        return out, {}
     if kind == "moe":
+        if cfg.moe_score == "sigmoid":     # DeepSeek-V3: dropless, aux-free
+            b, s, D = h.shape
+            out, counts = moe_dropless(
+                h.reshape(b * s, D), p["router"], p["moe_gate"], p["moe_up"],
+                p["moe_down"], top_k=cfg.top_k, scale=cfg.moe_routed_scale)
+            out = out.reshape(b, s, D)
+            if cfg.moe_shared_d_ff:
+                out = out + (jax.nn.silu(h @ p["shared_gate"])
+                             * (h @ p["shared_up"])) @ p["shared_down"]
+            return out, counts
         if cfg.moe_dispatch is not None:   # §Perf B3: manual EP (shard_map)
-            return cfg.moe_dispatch(
+            out, aux = cfg.moe_dispatch(
                 h, p["router"], p["moe_gate"], p["moe_up"], p["moe_down"],
                 top_k=cfg.top_k, capacity_factor=cfg.capacity_factor)
+            return out, {"aux": aux}
         from repro.models.layers import moe_layer_3d
         out, aux = moe_layer_3d(h, p["router"], p["moe_gate"], p["moe_up"],
                                 p["moe_down"], top_k=cfg.top_k,
                                 capacity_factor=cfg.capacity_factor,
                                 impl=cfg.moe_impl, ep_shard=cfg.act_shard_moe,
                                 seq_chunk=cfg.moe_seq_chunk, remat=cfg.remat)
-        return out, aux
+        return out, {"aux": aux}
     raise ValueError(kind)
 
 
@@ -351,10 +402,25 @@ def _mamba_body(p, x, cfg: ArchConfig, *, return_state: bool = False):
 
 def _apply_block(p, x, cfg: ArchConfig, kind: LayerKind, *, causal: bool,
                  positions=None, enc_out=None, collect_kv: bool):
-    """One block; returns (x, aux_loss, cache_contrib_or_None)."""
+    """One block; returns (x, stats, cache_contrib_or_None)."""
     contrib = None
-    aux = 0.0
-    if cfg.parallel_block and kind.mixer == "attn" and kind.mlp != "none":
+    aux = {}
+    if kind.mixer == "mla":
+        if collect_kv:
+            raise NotImplementedError("no serving cache for latent attention")
+        h = cfg.act_gather(rms_norm(x, p["attn_norm"], eps=cfg.norm_eps))
+        if positions is None:
+            positions = jnp.arange(x.shape[1])[None, :]
+        attn = mla_attention(
+            p, h, positions, n_heads=cfg.n_heads, nope=cfg.qk_nope_head_dim,
+            rope_dim=cfg.qk_rope_head_dim, v_dim=cfg.v_head_dim,
+            rank=cfg.kv_lora_rank, theta=cfg.rope_theta, eps=cfg.norm_eps,
+            causal=causal, impl=cfg.attn_impl, q_chunk=cfg.attn_q_chunk)
+        x = x + attn @ p["wo"]
+        if kind.mlp != "none":
+            mlp_out, aux = _mlp_body(p, x, cfg, kind.mlp)
+            x = x + mlp_out
+    elif cfg.parallel_block and kind.mixer == "attn" and kind.mlp != "none":
         # command-r: shared norm, attn & mlp in parallel
         attn_out, kv = _attn_body(p, x, cfg, causal=causal,
                                   positions=positions)
@@ -389,29 +455,40 @@ def _apply_block(p, x, cfg: ArchConfig, kind: LayerKind, *, causal: bool,
 # ---------------------------------------------------------------------------
 # stacks
 # ---------------------------------------------------------------------------
+def _add_stats(a: dict, b: dict) -> dict:
+    # Sorted, not a set's order: that follows the process's string hashes,
+    # and the order of the adds is part of the program (and of its key in
+    # the persistent compilation cache).
+    return {k: a.get(k, 0.0) + b.get(k, 0.0) for k in sorted(a.keys() | b.keys())}
+
+
 def _run_stack(stack, x, cfg: ArchConfig, plan, *, causal: bool,
                positions=None, enc_out=None, collect_kv: bool = False):
-    """scan over periods; returns (x, aux_total, cache_stack_or_None)."""
+    """scan over periods; returns (x, stats summed over the stack,
+    cache_stack_or_None).  ``stats``: ``aux`` (the auxiliary loss of
+    capacity-routed MoE blocks) and the dropless blocks' row counts."""
 
     def period_body(carry, pparams):
         x = carry
-        aux_tot = jnp.zeros((), jnp.float32)
+        stats = {}
         kvs = {}
         for i, kind in enumerate(plan):
-            x, aux, contrib = _apply_block(
+            x, st, contrib = _apply_block(
                 pparams[f"p{i}"], x, cfg, kind, causal=causal,
                 positions=positions, enc_out=enc_out, collect_kv=collect_kv)
-            aux_tot = aux_tot + aux
+            stats = _add_stats(stats, st)
             if collect_kv and contrib is not None:
                 kvs[f"p{i}"] = contrib
-        return x, (aux_tot, kvs)
+        stats = {k: jnp.asarray(v, jnp.float32) for k, v in stats.items()}
+        return x, (stats, kvs)
 
     body = period_body
     if cfg.remat:
         body = jax.checkpoint(period_body,
                               prevent_cse=False)
-    x, (auxs, kv_stack) = jax.lax.scan(body, x, stack)
-    return x, auxs.sum(), (kv_stack if collect_kv else None)
+    x, (stats, kv_stack) = jax.lax.scan(body, x, stack)
+    return (x, {k: v.sum() for k, v in stats.items()},
+            (kv_stack if collect_kv else None))
 
 
 def _embed_inputs(params, batch, cfg: ArchConfig):
@@ -452,12 +529,16 @@ def _hidden(params, batch, cfg: ArchConfig, *, collect_kv: bool = False):
     enc_out = None
     if cfg.enc_layers > 0:
         enc_out = _run_encoder(params, batch, cfg)
+    stats = {}
+    if cfg.first_dense_layers:
+        x, stats, _ = _run_stack(params["dense"], x, cfg, dense_plan(cfg),
+                                 causal=True, positions=positions)
     plan = layer_plan(cfg)
-    x, aux, kv = _run_stack(
+    x, st, kv = _run_stack(
         params["stack"], x, cfg, plan, causal=True, positions=positions,
         enc_out=enc_out, collect_kv=collect_kv)
     h = rms_norm(x, params["final_norm"], eps=cfg.norm_eps)
-    return h, loss_mask, aux, kv
+    return h, loss_mask, _add_stats(stats, st), kv
 
 
 def forward(params, batch, cfg: ArchConfig):
@@ -469,7 +550,14 @@ def forward(params, batch, cfg: ArchConfig):
 def loss_fn(params, batch, cfg: ArchConfig):
     """Next-token CE, chunked over the sequence so the [b,s,V] logits tensor
     is never materialized (vocab up to 256k × seq 4k would be 0.5TB)."""
-    h, loss_mask, aux, _ = _hidden(params, batch, cfg)
+    return loss_and_counts(params, batch, cfg)[0]
+
+
+def loss_and_counts(params, batch, cfg: ArchConfig):
+    """``loss_fn`` and the step's counts: the dropless expert layers' row
+    counts summed over the layers (``moe_routed_rows``,
+    ``moe_buffer_rows``); empty for a model without them."""
+    h, loss_mask, stats, _ = _hidden(params, batch, cfg)
     tokens = batch["tokens"]
     b, s_tot, D = h.shape
     s_text = tokens.shape[1]
@@ -507,14 +595,23 @@ def loss_fn(params, batch, cfg: ArchConfig):
           jnp.moveaxis(mask.reshape(b, nc, chunk), 1, 0))
     total, _ = jax.lax.scan(chunk_loss, jnp.zeros((), jnp.float32), xs)
     denom = jnp.maximum(mask.sum(), 1.0)
-    return total / denom + cfg.moe_aux_weight * aux
+    counts = {k: v for k, v in stats.items() if k != "aux"}
+    return total / denom + cfg.moe_aux_weight * stats.get("aux", 0.0), counts
 
 
 # ---------------------------------------------------------------------------
 # serving: cache init / prefill / decode
 # ---------------------------------------------------------------------------
+def _check_servable(cfg: ArchConfig) -> None:
+    if not cfg.servable:
+        raise NotImplementedError(
+            f"{cfg.name}: no serving cache for latent attention or leading "
+            "dense layers (the model is trained, not served, here)")
+
+
 def init_cache(cfg: ArchConfig, batch: int, max_len: int):
     """Allocate the serving cache for a batch of sequences of ≤ max_len."""
+    _check_servable(cfg)
     plan = layer_plan(cfg)
     n_periods = cfg.n_layers // len(plan)
     dtype = jnp.dtype(cfg.dtype)
@@ -551,6 +648,7 @@ def prefill(params, batch, cfg: ArchConfig, *, max_len: int | None = None):
     Fills attention k/v (first ``s`` slots), mamba conv/ssm states, and the
     whisper cross-attention k/v, so ``decode_step`` can continue at pos=s.
     """
+    _check_servable(cfg)
     h, _, _, kv = _hidden(params, batch, cfg, collect_kv=True)
     logits = _lm_head(params, h[:, -1:, :], cfg)[:, 0]
     if cfg.padded_vocab != cfg.vocab_size:
@@ -624,6 +722,7 @@ def decode_step(params, cache, tokens, pos, cfg: ArchConfig):
 
     Returns (logits [b,V], new_cache).
     """
+    _check_servable(cfg)
     from dataclasses import replace as _replace
     # Decode batches are tiny (T = b tokens); run MoE droppless by setting
     # capacity to the worst case C = T (capacity_factor = E/k) — capacity

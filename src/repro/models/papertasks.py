@@ -1,5 +1,6 @@
 """The paper's four FL-task models (§5.1), sized to the paper's tasks but
-operating on the synthetic federated datasets of ``repro.data.federated``:
+operating on the synthetic federated datasets of ``repro.data.federated``,
+and one public LM client architecture:
 
 * IC  — Image Classification: ShuffleNet-style MLP-mixer over feature
         vectors, 596 classes (OpenImage).
@@ -8,6 +9,10 @@ operating on the synthetic federated datasets of ``repro.data.federated``:
 * TG  — Text Generation: two-cell LSTM language model (Shakespeare / LEAF).
 * MLM — Masked Language Modelling: RoBERTa-style bidirectional transformer
         encoder with a masked-token objective (Reddit).
+* kanana2-a3b — one chip's share of kanana-2-30b-a3b (DeepSeek-V3-style
+        latent attention and dropless sigmoid-routed experts, the unified LM
+        stack of ``repro.models.lm``) with a causal next-token loss, over the
+        MLM population (``repro.configs.kanana_2_30b_a3b.SHARE``).
 
 The paper treats these as opaque client workloads; what matters for Pollen is
 their *training-time* and *model-size* profiles (Table 6: TG 3.28MB,
@@ -223,16 +228,33 @@ def _mlm_loss(p, batch, *, mask_rate=0.15, mask_token=3):
     return -(gold * m).sum() / jnp.maximum(m.sum(), 1.0)
 
 
+def _kanana_init(key):
+    from repro.configs.kanana_2_30b_a3b import SHARE
+    from repro.models.lm import init_params
+    # One program: op-by-op, every leaf's draw would compile on its own.
+    return jax.jit(lambda k: init_params(k, SHARE))(key)
+
+
+def _kanana_loss(p, batch):
+    """(loss, the dropless expert layers' row counts)."""
+    from repro.configs.kanana_2_30b_a3b import SHARE
+    from repro.models.lm import loss_and_counts
+    return loss_and_counts(p, batch, SHARE)
+
+
 TASK_MODELS = {
     "ic": TaskModel("ic", ic_init, _ic_loss, 26.45e6, "labelled"),
     "sr": TaskModel("sr", sr_init, _sr_loss, 85.14e6, "labelled"),
     "tg": TaskModel("tg", tg_init, _tg_loss, 3.28e6, "tokens"),
     "mlm": TaskModel("mlm", mlm_init, _mlm_loss, 60.37e6, "tokens"),
+    # 424,960,512 float32 parameters
+    "kanana2-a3b": TaskModel("kanana2-a3b", _kanana_init, _kanana_loss,
+                             1_699_842_048.0, "tokens"),
 }
 
 
 def make_task_model(task: str, key, **kw):
-    """Returns (params, loss_fn) for one of the paper's four tasks."""
+    """Returns (params, loss_fn) for a task of ``TASK_MODELS``."""
     tm = TASK_MODELS[task]
     if task == "tg":
         kw.setdefault("vocab", 32_000)

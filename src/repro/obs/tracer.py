@@ -150,11 +150,12 @@ class Tracer:
         tl.ring.append(("I", name, time.perf_counter(), 0.0, tl.lane,
                         tl.depth, attrs or None))
 
-    def counter(self, name: str, value: float) -> None:
-        """A counter-track sample (cache hit rate, online pool, bytes)."""
+    def counter(self, name: str, value: float, **attrs) -> None:
+        """A counter-track sample (cache hit rate, online pool, bytes);
+        ``attrs`` (such as the round index ``t``) ride on the record."""
         tl = self._tl()
         tl.ring.append(("C", name, time.perf_counter(), float(value),
-                        tl.lane, 0, None))
+                        tl.lane, 0, attrs or None))
 
     def add_span(self, name: str, t0: float, dur: float, *,
                  lane: str | None = None, **attrs) -> None:
@@ -219,7 +220,7 @@ class NullTracer:
     def instant(self, name, **attrs):
         pass
 
-    def counter(self, name, value):
+    def counter(self, name, value, **attrs):
         pass
 
     def add_span(self, name, t0, dur, *, lane=None, **attrs):

@@ -133,6 +133,7 @@ def test_param_counts_match_published_sizes():
         "command-r-plus-104b": 104e9, "granite-moe-3b-a800m": 3.3e9,
         "qwen3-moe-235b-a22b": 235e9, "internvl2-26b": 20e9,  # LM backbone
         "jamba-v0.1-52b": 52e9, "whisper-base": 74e6, "mamba2-2.7b": 2.7e9,
+        "kanana-2-30b-a3b": 30e9,
     }
     for arch, want in targets.items():
         shapes = jax.eval_shape(lambda k, c=ARCHS[arch]: init_params(k, c),

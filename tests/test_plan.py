@@ -16,23 +16,32 @@ def _mesh():
 
 
 def test_long500k_skips_match_assignment():
-    """Exactly the 8 pure-full-attention archs skip long_500k; the ssm and
-    hybrid archs run it — 32 runnable + 8 skips = 40 cells."""
+    """Exactly the 8 pure-full-attention archs of the assignment and
+    kanana-2-30b-a3b (latent attention is full attention) skip long_500k;
+    the ssm and hybrid archs run it.  kanana-2-30b-a3b, trained and not
+    served, also skips the two serve shapes — 33 runnable + 11 skips = 44
+    cells."""
     skips = [a for a in ARCH_NAMES if not runnable(ARCHS[a], "long_500k")]
-    assert len(skips) == 8
+    assert len(skips) == 9
     assert set(skips) == set(ARCH_NAMES) - {"mamba2-2.7b", "jamba-v0.1-52b"}
+    unservable = {a for a in ARCH_NAMES if not ARCHS[a].servable}
+    assert unservable == {"kanana-2-30b-a3b"}
     for a in ARCH_NAMES:
         for s in SHAPES:
             if s != "long_500k":
-                assert runnable(ARCHS[a], s)
+                assert runnable(ARCHS[a], s) == (a not in unservable
+                                                 or not SHAPES[s].is_serve)
     total_runnable = sum(runnable(ARCHS[a], s)
                          for a in ARCH_NAMES for s in SHAPES)
-    assert total_runnable == 32
+    assert total_runnable == 33
 
 
 def test_skip_reason_text():
     assert "quadratic" in skip_reason(ARCHS["qwen3-0.6b"], "long_500k")
     assert skip_reason(ARCHS["mamba2-2.7b"], "long_500k") is None
+    for s in ("prefill_32k", "decode_32k"):
+        assert "serving cache" in skip_reason(ARCHS["kanana-2-30b-a3b"], s)
+    assert skip_reason(ARCHS["kanana-2-30b-a3b"], "train_4k") is None
 
 
 @pytest.mark.parametrize("arch", ARCH_NAMES)

@@ -60,6 +60,8 @@ ARCHITECTURE_NEEDLES = (
     "exec.host_merge", "O(hosts)",
     # the placement feature: the steps a client trains this round
     "The placement feature", "capped_clients",
+    # the dropless expert layer and one chip's expert share
+    "The dropless expert layer", "the expert share", "moe_dropless",
 )
 
 # What docs/OBSERVABILITY.md must keep covering: the tracer's ring
@@ -77,6 +79,7 @@ OBSERVABILITY_NEEDLES = (
     "--flight-rounds", "SIGTERM", "never to raise",
     "tracer_overhead_fraction", "trend_summary.json",
     "state_dict", ".aux.npz", "exec.host_merge", "capped_clients",
+    "moe_routed_rows", "moe_buffer_rows", "moe_row_waste_share",
 )
 
 # What docs/POPULATION.md must keep covering: the registry's hash streams,
