@@ -242,6 +242,10 @@ class RoundResult:
     #                                token-rows routed to the held experts
     moe_buffer_rows: int = 0       # rows the held experts' grouped
     #                                products are given (fused step only)
+    head_rows: int = 0             # masked tied head (mlm): the masked rows
+    #                                the loss reads, over the real steps
+    head_buffer_rows: int = 0      # rows whose head logits were computed
+    #                                (trips x chunk; fused step only)
     # -- round critique (repro.obs; see docs/OBSERVABILITY.md) -------------
     idle_fraction: float = 0.0     # simulated worker-seconds left idle:
     #                                idle_time / (makespan * n_workers) —
@@ -1566,7 +1570,9 @@ class FederatedEngine:
             online_pool=prep.online_pool,
             capped_clients=prep.capped_clients,
             moe_routed_rows=int(counts.get("moe_routed_rows", 0)),
-            moe_buffer_rows=int(counts.get("moe_buffer_rows", 0)))
+            moe_buffer_rows=int(counts.get("moe_buffer_rows", 0)),
+            head_rows=int(counts.get("head_rows", 0)),
+            head_buffer_rows=int(counts.get("head_buffer_rows", 0)))
         # Round critique (repro.obs): idle_fraction comes from the
         # deterministic placement simulation, so it is bit-identical across
         # depths and tracer on/off; critical_path is timing-derived (like
@@ -1591,9 +1597,8 @@ class FederatedEngine:
             tr.counter("online_pool", prep.online_pool)
             tr.counter("capped_clients", float(prep.capped_clients))
             tr.counter("combine_bytes", float(prep.combine_bytes))
-            for name in ("moe_routed_rows", "moe_buffer_rows"):
-                if name in counts:
-                    tr.counter(name, float(counts[name]), t=t)
+            for name in sorted(counts):
+                tr.counter(name, float(counts[name]), t=t)
         if self._metrics is not None:
             m = self._metrics
             m.inc("rounds")

@@ -10,7 +10,10 @@ Execution model (the TPU adaptation of Pollen's worker processes):
   is folded into the worker's partial aggregate (Eq. 1) and parameters reset
   to the global model — then client k+1's batches, and so on;
 * all lanes are padded to the longest stream ``S``.  Padded steps are masked
-  (zero gradient, zero aggregation weight) — **pure waste**.
+  (zero gradient, zero aggregation weight) — **pure waste**.  They carry a
+  copy of the round's first batch, not zeros: a step whose cost follows its
+  content (the ``mlm`` head's trips over its masked rows) then costs there
+  what a real step costs, since an all-zero token batch is all masked.
 
 The makespan of lane streams is exactly the paper's straggler/idle-time
 metric: LB placement balances predicted per-worker time, which here minimizes
@@ -203,9 +206,10 @@ class PackBuffers:
     ``depth`` slots per distinct (W, P, S, leaf-spec) key rotate round-robin:
     the pipelined engine needs ``pipeline_depth + 1`` so the background
     packer never writes the buffer whose device copy may still be in flight.
-    Mask arrays are zeroed on reuse (cheap, [W, P, S]); batch arrays are left
-    **stale** — every padded slot is masked out by ``step_mask`` in the
-    compiled step, so their content never reaches the model update.
+    Mask arrays are zeroed on reuse (cheap, [W, P, S]); batch arrays are
+    overwritten, padded slots included (with the round's first batch) —
+    every padded slot is masked out by ``step_mask`` in the compiled step,
+    so their content never reaches the model update.
     """
 
     def __init__(self, depth: int = 2):
@@ -317,9 +321,11 @@ def build_round_arrays(dataset, assignment=None, workers=None, *,
 
     if plan.n_steps_total:
         idx = (plan.w_idx, plan.p_idx, plan.s_idx)
+        step_mask[idx] = 1.0
+        pad = step_mask == 0
         for name, arr in vals.items():
             batches[name][idx] = arr
-        step_mask[idx] = 1.0
+            batches[name][pad] = arr[0]
         boundary[plan.b_w, plan.b_p, plan.b_s] = 1.0
         weight[plan.b_w, plan.b_p, plan.b_s] = plan.b_weight
 
@@ -428,18 +434,23 @@ def build_round_arrays_loop(dataset, assignment, workers, *,
     boundary = np.zeros((W, P, S), dtype=np.float32)
     weight = np.zeros((W, P, S), dtype=np.float32)
 
+    first = None
     for (wi, p), lane in streams.items():
         s = 0
         for c, nb in lane:
             for bi in range(nb):
                 b = dataset.client_batch(c.cid, bi, batch_size=batch_size,
                                          seq_len=seq_len)
+                first = b if first is None else first
                 for name, arr in b.items():
                     batches[name][wi, p, s] = np.asarray(arr)
                 step_mask[wi, p, s] = 1.0
                 s += 1
             boundary[wi, p, s - 1] = 1.0       # fold this client at its last step
             weight[wi, p, s - 1] = float(c.weight)
+    if first is not None:                      # padded slots: the first batch
+        for name, arr in first.items():
+            batches[name][step_mask == 0] = np.asarray(arr)
 
     return RoundArrays(batches=batches, step_mask=step_mask, boundary=boundary,
                        weight=weight, n_steps=S)

@@ -205,7 +205,8 @@ def build_engine(*, task: str | None = None, arch: str | None = None,
     else:
         task = task or "sr"
         client = TASK_CLIENTS[task]
-        params, loss_fn = make_task_model(task, key)
+        params, _ = make_task_model(task, key)
+        loss_fn = TASK_MODELS[task].loss_fn     # with the task's counts
         ds = make_federated_dataset(
             task, seed=seed, **client.get("data", dict)(),
             **({"n_clients": base_clients} if base_clients else {}))

@@ -17,6 +17,7 @@ Attention supports three implementations (the §Perf knob):
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -25,7 +26,7 @@ import jax.numpy as jnp
 __all__ = [
     "rms_norm", "rope", "rope_interleaved", "gqa_attention", "mla_attention",
     "swiglu", "gelu_mlp", "moe_layer", "grouped_matmul", "route_topk",
-    "moe_dropless",
+    "moe_dropless", "masked_tied_xent", "xent_chunk_rows",
     "dense_init", "norm_init", "causal_scores_mask", "decode_attention",
 ]
 
@@ -421,6 +422,119 @@ def moe_dropless(x, router_w, moe_gate, moe_up, moe_down, *, top_k: int,
     counts = {"moe_routed_rows": held.sum().astype(jnp.float32),
               "moe_buffer_rows": jnp.float32(T * top_k)}
     return out.astype(x.dtype), counts
+
+
+# --------------------------------------------------------------------------
+# tied-head cross-entropy over the masked rows
+# --------------------------------------------------------------------------
+def xent_chunk_rows(tokens: int, rate: float) -> int:
+    """Rows one trip of ``masked_tied_xent`` takes: the multiple of 128 at
+    or above 1.25 x the expected masked rows, at most ``tokens`` (512 for
+    20 x 128 tokens at rate 0.15)."""
+    return min(tokens, 128 * max(1, math.ceil(1.25 * rate * tokens / 128)))
+
+
+def _xent_trips(n, C):
+    """Trips over the compacted rows; the first is taken even with none."""
+    return jnp.maximum(1, (n + C - 1) // C)
+
+
+def _over_trips(trip, n, C, carry):
+    """``carry = trip(k, *carry)`` for each trip k.  Trip 0 runs straight
+    and the loop takes the rest (none on a batch of at most C masked rows),
+    so the common case pays no loop-carried select under the lane vmap."""
+    carry = trip(0, *carry)
+    return jax.lax.while_loop(
+        lambda c: c[0] < _xent_trips(n, C),
+        lambda c: (c[0] + 1,) + trip(c[0], *c[1:]),
+        (jnp.ones((), n.dtype),) + carry)[1:]
+
+
+def _xent_chunk(h, embed, labels, rows, k, C):
+    """Trip ``k``: its rows of h, their logits through the tied head (the
+    backend's default precision) and their labels."""
+    r = jax.lax.dynamic_slice_in_dim(rows, k * C, C)
+    hk = h[r]
+    return hk, hk @ embed.T, labels[r]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _compact_xent(h, embed, labels, mask, rows, n, C):
+    return _compact_xent_fwd(h, embed, labels, mask, rows, n, C)[0]
+
+
+def _compact_xent_fwd(h, embed, labels, mask, rows, n, C):
+    Tp = rows.shape[0]
+    valid = jnp.arange(Tp) < n
+
+    def trip(k, nll, lse_buf):
+        _, logits, lab = _xent_chunk(h, embed, labels, rows, k, C)
+        lse = jax.nn.logsumexp(logits, -1)
+        gold = jnp.take_along_axis(logits, lab[:, None], -1)[:, 0]
+        v = jax.lax.dynamic_slice_in_dim(valid, k * C, C)
+        nll = nll + jnp.where(v, lse - gold, 0.0).sum()
+        return nll, jax.lax.dynamic_update_slice_in_dim(lse_buf, lse, k * C, 0)
+
+    nll, lse_buf = _over_trips(trip, n, C, (jnp.zeros((), jnp.float32),
+                                            jnp.zeros((Tp,), jnp.float32)))
+    loss = nll / jnp.maximum(n, 1).astype(jnp.float32)
+    return loss, (h, embed, labels, mask, rows, n, lse_buf)
+
+
+def _compact_xent_bwd(C, res, g):
+    h, embed, labels, mask, rows, n, lse_buf = res
+    Tp = rows.shape[0]
+    V = embed.shape[0]
+    scale = jnp.where(jnp.arange(Tp) < n,
+                      g / jnp.maximum(n, 1).astype(jnp.float32), 0.0)
+
+    def trip(k, dE, dh_c):
+        hk, logits, lab = _xent_chunk(h, embed, labels, rows, k, C)
+        lse = jax.lax.dynamic_slice_in_dim(lse_buf, k * C, C)
+        s = jax.lax.dynamic_slice_in_dim(scale, k * C, C)
+        # (softmax - onehot) * g / n, zero on the buffer's padding rows
+        dlog = (jnp.exp(logits - lse[:, None])
+                - (jnp.arange(V)[None, :] == lab[:, None])) * s[:, None]
+        dE = dE + jnp.einsum("cv,cd->vd", dlog, hk)
+        return dE, jax.lax.dynamic_update_slice_in_dim(dh_c, dlog @ embed,
+                                                       k * C, 0)
+
+    dE, dh_c = _over_trips(trip, n, C, (jnp.zeros_like(embed),
+                                        jnp.zeros((Tp,) + h.shape[1:], h.dtype)))
+    # Back to token order: row t is the (cumsum(mask)[t] - 1)-th compacted.
+    pos = jnp.maximum(jnp.cumsum(mask) - 1, 0)
+    dh = jnp.where(mask[:, None], dh_c[pos], 0.0).astype(h.dtype)
+    return dh, dE.astype(embed.dtype), None, None, None, None
+
+
+_compact_xent.defvjp(_compact_xent_fwd, _compact_xent_bwd)
+
+
+def masked_tied_xent(h, embed, labels, mask, *, rate: float):
+    """Mean cross-entropy of ``labels`` at the masked positions, through the
+    tied head ``embed`` [V, D], computed on the masked rows alone.
+
+    h [..., D] hidden states, labels and mask [...] over the same positions.
+    The masked rows are compacted, in order, into a buffer and taken ``C``
+    at a time (``xent_chunk_rows`` of the token count and the mask's
+    expected ``rate``), ``max(1, ceil(count / C))`` trips; no row is ever
+    dropped.  An unmasked row's cotangent through the softmax is exactly
+    zero, so this is the full-logits masked mean, up to the order of the
+    sum over rows.  The backward recomputes each trip's logits.
+
+    Returns (loss, counts): ``head_rows``, the masked rows the loss reads,
+    and ``head_buffer_rows``, trips x C, the rows whose logits were
+    computed."""
+    D = h.shape[-1]
+    h, labels, mask = h.reshape(-1, D), labels.reshape(-1), mask.reshape(-1)
+    T = labels.shape[0]
+    C = xent_chunk_rows(T, rate)
+    n = mask.sum(dtype=jnp.int32)
+    (rows,) = jnp.nonzero(mask, size=-(-T // C) * C, fill_value=0)
+    loss = _compact_xent(h, embed, labels, mask, rows, n, C)
+    counts = {"head_rows": n.astype(jnp.float32),
+              "head_buffer_rows": (_xent_trips(n, C) * C).astype(jnp.float32)}
+    return loss, counts
 
 
 def _moe_dispatch(x, router_w, moe_gate, moe_up, moe_down, *, top_k: int,

@@ -25,13 +25,14 @@ run under the federated round step.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Callable
 
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import dense_init
+from repro.models.layers import dense_init, masked_tied_xent
 
 __all__ = ["TaskModel", "TASK_MODELS", "make_task_model"]
 
@@ -165,7 +166,9 @@ def mlm_init(key, *, vocab=30_000, d_model=256, n_layers=4, n_heads=4,
     return p
 
 
-def mlm_forward(p, tokens, *, n_heads: int = 4):
+def mlm_encode(p, tokens, *, n_heads: int = 4):
+    """The encoder: tokens [b, s] -> hidden states [b, s, d], before the
+    tied head."""
     x = p["embed"][tokens]
     b, s, d = x.shape
     nh = n_heads
@@ -187,7 +190,7 @@ def mlm_forward(p, tokens, *, n_heads: int = 4):
 
     x, _ = jax.lax.scan(layer, x, (p["wq"], p["wk"], p["wv"], p["wo"],
                                    p["w_up"], p["w_down"], p["ln1"], p["ln2"]))
-    return x @ p["embed"].T
+    return x
 
 
 # ---------------------------------------------------------------------------
@@ -197,7 +200,8 @@ def mlm_forward(p, tokens, *, n_heads: int = 4):
 class TaskModel:
     name: str
     init: Callable
-    loss_fn: Callable            # (params, batch) -> scalar
+    loss_fn: Callable            # (params, batch) -> scalar, or
+    #                              (scalar, counts) for mlm and kanana2-a3b
     target_bytes: float          # paper Table 6 model size (MB -> bytes)
     kind: str                    # 'labelled' | 'tokens'
 
@@ -216,16 +220,19 @@ def _tg_loss(p, batch):
     return _xent(logits, toks[:, 1:])
 
 
+@functools.partial(jax.jit, static_argnames=("mask_rate", "mask_token"))
 def _mlm_loss(p, batch, *, mask_rate=0.15, mask_token=3):
+    """(loss, the tied head's row counts): the cross-entropy at the masked
+    positions, the head run on those rows alone (``masked_tied_xent``).
+
+    Jitted so that each S bucket's round-step program reuses one trace of
+    it (the encoder's layer scan, the head's custom VJP and its loops)
+    instead of tracing and batching it anew; XLA inlines the call."""
     toks = batch["tokens"]
     # deterministic pseudo-mask from token content (no rng plumbing needed)
     mask = (toks * 2_654_435 % 100) < int(mask_rate * 100)
-    inp = jnp.where(mask, mask_token, toks)
-    logits = mlm_forward(p, inp)
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-    gold = jnp.take_along_axis(logp, toks[..., None], -1)[..., 0]
-    m = mask.astype(jnp.float32)
-    return -(gold * m).sum() / jnp.maximum(m.sum(), 1.0)
+    h = mlm_encode(p, jnp.where(mask, mask_token, toks))
+    return masked_tied_xent(h, p["embed"], toks, mask, rate=mask_rate)
 
 
 def _kanana_init(key):
@@ -253,12 +260,22 @@ TASK_MODELS = {
 }
 
 
+def _loss_only(loss_fn):
+    def f(params, batch):
+        out = loss_fn(params, batch)
+        return out[0] if isinstance(out, tuple) else out
+
+    return f
+
+
 def make_task_model(task: str, key, **kw):
-    """Returns (params, loss_fn) for a task of ``TASK_MODELS``."""
+    """Returns (params, loss_fn) for a task of ``TASK_MODELS``, ``loss_fn``
+    the scalar loss (the round engine takes ``TaskModel.loss_fn``, with
+    the task's counts)."""
     tm = TASK_MODELS[task]
     if task == "tg":
         kw.setdefault("vocab", 32_000)
     if task == "mlm":
         kw.setdefault("vocab", 32_000)
     params = tm.init(key, **kw)
-    return params, tm.loss_fn
+    return params, _loss_only(tm.loss_fn)

@@ -92,6 +92,26 @@ def test_s_align_allocates_bucketed_no_pad_needed():
         assert v.shape[2] == arrays.n_steps
 
 
+def test_padded_slots_carry_a_batch_of_the_round():
+    """Padded slots repeat the round's first batch, in a fresh buffer and in
+    a reused one alike: never zeros (all masked for the mlm head) nor an
+    earlier round's content."""
+    ds = make_federated_dataset("tg")
+    buf = PackBuffers(depth=1)
+    for seed in (3, 4):
+        assignment, workers = _random_assignment(np.random.default_rng(seed),
+                                                 ds, 5, 2)
+        arrays = build_round_arrays(ds, assignment, workers,
+                                    lanes_per_worker=2, steps_cap=3,
+                                    batch_size=2, seq_len=16,
+                                    s_align=lambda s: 8, buffers=buf)
+        toks, real = arrays.batches["tokens"], arrays.step_mask > 0
+        assert (~real).any()
+        padded = toks[~real]
+        assert (padded == padded[0]).all() and padded[0].any()
+        assert (toks[real] == padded[0]).all(axis=(1, 2)).any()
+
+
 def test_pack_buffers_ring_reuses_and_isolates():
     ds = make_federated_dataset("sr", n_clients=64, input_dim=8, batch_size=2)
     rng = np.random.default_rng(9)
