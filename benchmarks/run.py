@@ -16,8 +16,7 @@ def main() -> int:
     from benchmarks import (bench_aggregation, bench_concurrency,
                             bench_control, bench_fit, bench_frameworks,
                             bench_kernels, bench_pipeline, bench_placement,
-                            bench_roofline, bench_scalability,
-                            bench_utilization)
+                            bench_scalability, bench_utilization)
 
     table = {
         "pipeline": (bench_pipeline, "pack / deep pipeline / device cache"),
@@ -31,7 +30,6 @@ def main() -> int:
         "utilization": (bench_utilization, "Tables 4/5 — GPU util / VRAM"),
         "concurrency": (bench_concurrency, "Table 3 — concurrency estimate"),
         "kernels": (bench_kernels, "Pallas kernels — err + HBM traffic"),
-        "roofline": (bench_roofline, "§Roofline — dry-run derived table"),
     }
     picks = [a for a in sys.argv[1:] if a in table] or list(table)
     failures = []

@@ -1,7 +1,5 @@
 """Batched serving example: prefill a batch of prompts, then decode tokens
-step by step against the KV cache — the same ``prefill``/``decode_step``
-functions the decode_32k / long_500k dry-run cells lower at production
-scale.
+step by step against the KV cache (``lm.prefill``/``lm.decode_step``).
 
     PYTHONPATH=src python examples/serve_lm.py [--arch mamba2-2.7b]
 """

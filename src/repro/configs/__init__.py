@@ -1,9 +1,9 @@
 """Architecture registry: the 10 assigned architectures and
-kanana-2-30b-a3b (exact public hyperparameters) + the shape cells.
+kanana-2-30b-a3b (exact public hyperparameters).
 ``get_arch(name)`` accepts either the canonical dashed id
 (``--arch qwen3-0.6b``) or the module name."""
 
-from repro.configs.base import SHAPES, ArchConfig, FLPlan, ShapeConfig
+from repro.configs.base import ArchConfig
 
 from repro.configs import (command_r_plus_104b, granite_moe_3b_a800m,
                            internlm2_1_8b, internvl2_26b, jamba_v0_1_52b,
@@ -33,5 +33,4 @@ def get_arch(name: str) -> ArchConfig:
     raise KeyError(f"unknown architecture {name!r}; known: {ARCH_NAMES}")
 
 
-__all__ = ["ARCHS", "ARCH_NAMES", "get_arch", "ArchConfig", "ShapeConfig",
-           "SHAPES", "FLPlan"]
+__all__ = ["ARCHS", "ARCH_NAMES", "get_arch", "ArchConfig"]

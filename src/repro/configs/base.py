@@ -1,17 +1,15 @@
-"""Architecture + shape configuration schema.
+"""Architecture configuration schema.
 
 One ``ArchConfig`` per assigned architecture (exact hyperparameters from the
-assignment table) plus the paper's own four FL-task models.  ``ShapeConfig``
-describes the assigned input-shape cells; ``reduced()`` produces the smoke-
-test scale of the same family.
+assignment table); ``reduced()`` produces the smoke-test scale of the same
+family.
 """
 
 from __future__ import annotations
 
-import dataclasses
 from dataclasses import dataclass, replace
 
-__all__ = ["ArchConfig", "ShapeConfig", "SHAPES", "FLPlan"]
+__all__ = ["ArchConfig"]
 
 
 @dataclass(frozen=True)
@@ -81,15 +79,13 @@ class ArchConfig:
     parallel_block: bool = False  # command-r style attn ∥ mlp with shared norm
     rope: bool = True
     learned_pos: bool = False   # whisper decoder absolute positions
-    max_position: int = 0       # learned-pos table size (set by the planner)
+    max_position: int = 0       # learned-pos table size (set by build_engine)
     ssm_groups: int = 1         # B/C groups (mamba2 'ngroups')
 
     # execution knobs (the §Perf hillclimbing surface; swapped via replace())
     attn_impl: str = "dense"    # 'dense' | 'chunked' | 'pallas'
     attn_q_chunk: int = 512
-    attn_repeat_kv: bool = False  # repeat kv to Hq (even TP head sharding)
     moe_impl: str = "einsum"    # 'einsum' | 'scatter'
-    moe_seq_chunk: int = 0      # >0: dispatch in seq blocks (caps buffers)
     ssd_impl: str = "chunked"   # 'chunked' | 'recurrent' | 'pallas'
     ssd_chunk: int = 128
     remat: bool = False         # jax.checkpoint around each period body
@@ -98,17 +94,6 @@ class ArchConfig:
 
     # numerics
     dtype: str = "bfloat16"
-
-    # sharding hooks injected by the launcher (identity by default); excluded
-    # from to_dict().  These are with_sharding_constraint partials.
-    act_shard: object = staticmethod(lambda x: x)
-    act_shard_logits: object = staticmethod(lambda x: x)
-    act_shard_moe: object = None   # expert-buffer constraint ([E, C, ...])
-    moe_dispatch: object = None    # manual EP dispatch (shard_map; §Perf B3)
-    # Megatron-SP gather point: inside each block, after the norm, the
-    # sequence dim is gathered (batch stays sharded) so projections contract
-    # against TP-sharded weights without XLA re-gathering the weights.
-    act_gather: object = staticmethod(lambda x: x)
 
     # --- derived -----------------------------------------------------------
     @property
@@ -148,15 +133,6 @@ class ArchConfig:
         """Has a serving cache (``init_cache``/``prefill``/``decode_step``):
         none for latent attention or leading dense layers."""
         return not (self.mla or self.first_dense_layers)
-
-    @property
-    def sub_quadratic(self) -> bool:
-        """Can this arch run long_500k? (SSM / hybrid / linear-attn.)"""
-        return self.family in ("ssm", "hybrid")
-
-    @property
-    def has_decoder(self) -> bool:
-        return True  # all assigned archs have decoders (whisper is enc-dec)
 
     @property
     def resolved_frontend_dim(self) -> int:
@@ -228,48 +204,3 @@ class ArchConfig:
         kw["loss_chunk"] = 0
         kw["remat"] = False
         return replace(self, **kw)
-
-    def to_dict(self) -> dict:
-        d = {}
-        for f in dataclasses.fields(self):
-            v = getattr(self, f.name)
-            if callable(v):
-                continue
-            d[f.name] = v
-        return d
-
-
-@dataclass(frozen=True)
-class ShapeConfig:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str                   # 'train' | 'prefill' | 'decode'
-
-    @property
-    def is_serve(self) -> bool:
-        return self.kind in ("prefill", "decode")
-
-
-SHAPES: dict[str, ShapeConfig] = {
-    "train_4k":    ShapeConfig("train_4k",    4_096,   256, "train"),
-    "prefill_32k": ShapeConfig("prefill_32k", 32_768,  32,  "prefill"),
-    "decode_32k":  ShapeConfig("decode_32k",  32_768,  128, "decode"),
-    "long_500k":   ShapeConfig("long_500k",   524_288, 1,   "decode"),
-}
-
-
-@dataclass(frozen=True)
-class FLPlan:
-    """How a federated round maps onto the mesh for one (arch × shape).
-
-    worker_axes: mesh axes that index FL workers (W = their product).
-    lanes (P), steps (S), per-step batch (b): W*P*S*b == global_batch.
-    batch_axes: mesh axes the per-step batch dim is sharded over.
-    """
-
-    worker_axes: tuple = ("data",)
-    lanes: int = 1
-    steps: int = 2
-    batch: int = 8
-    batch_axes: tuple = ()

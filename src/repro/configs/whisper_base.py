@@ -24,7 +24,7 @@ CONFIG = ArchConfig(
     tie_embeddings=True,    # whisper ties decoder embed/proj
     rope=False,
     learned_pos=True,
-    max_position=4096,      # covers train_4k; the planner widens it per shape
+    max_position=4096,      # build_engine widens it to the preset's seq_len
     mlp_act="gelu",
     frontend="audio",
     frontend_len=1500,      # 30 s of post-conv frames

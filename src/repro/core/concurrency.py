@@ -10,9 +10,9 @@ working set) fit in the group's combined HBM next to the global copy and the
 round's activations.  Two estimators are provided:
 
 * :func:`estimate_slots_analytic` — closed-form from parameter/activation
-  byte counts (used by the planner before any compilation exists).
-* :func:`estimate_slots_from_memory_analysis` — refined from the compiled
-  dry-run's ``memory_analysis()`` (the TPU analogue of the paper's
+  byte counts (before any compilation exists).
+* :func:`estimate_slots_from_memory_analysis` — refined from a compiled
+  round step's ``memory_analysis()`` (the TPU analogue of the paper's
   probe-one-client-then-read-nvidia-smi step).
 
 Both return the concurrency level plus the per-slot byte breakdown so the

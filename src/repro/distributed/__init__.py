@@ -1,4 +1,3 @@
 from .elastic import WorkerPool, FailureEvent
-from .sharding import ShardingRules, make_sharding_rules
 
-__all__ = ["WorkerPool", "FailureEvent", "ShardingRules", "make_sharding_rules"]
+__all__ = ["WorkerPool", "FailureEvent"]

@@ -1,37 +1,15 @@
-"""Sharding rules: map model/optimizer/batch pytrees to PartitionSpecs.
+"""Where the mesh path's FL workers run.
 
-Convention: model parameters are nested dicts whose leaf *paths* follow the
-naming in ``repro.models`` (e.g. ``layers/attn_wq``, ``embed``, ``moe_w_up``).
-A :class:`ShardingRules` is an ordered list of (path-regex, spec-template);
-the first match wins.  Spec templates name *logical* axes which are resolved
-to mesh axes through the policy's axis map:
-
-    logical axes:  "tp"   — tensor-parallel (heads / ffn / vocab dims)
-                   "fsdp" — fully-sharded param dim (usually d_model)
-                   "ep"   — expert-parallel (MoE expert dim)
-                   "fl"   — the FL-worker dim of round arrays
-                   None   — replicated
-
-Policies (the hillclimbing knob — §Perf changes swap policies, not models):
-
-* ``tp``         : TP only; params replicated over data/pod (small archs).
-* ``fsdp_tp``    : TP + param FSDP over the data (and pod) axes (large archs).
-* ``fsdp_tp_ep`` : like fsdp_tp but MoE experts sharded over the TP axis.
-
-The FL-worker dim (W) of round arrays is sharded over whatever axes the
-plan designates as worker axes ("data", or "pod", or both).
+:class:`WorkerShardMap` maps FL workers onto the mesh's worker shards, and
+:class:`HostShardMap` partitions those shards among the hosts of the
+``hosts=H`` level.
 """
 
 from __future__ import annotations
 
-import re
 from dataclasses import dataclass
 
-import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-__all__ = ["ShardingRules", "make_sharding_rules", "spec_for_tree",
-           "named_shardings", "WorkerShardMap", "HostShardMap"]
+__all__ = ["WorkerShardMap", "HostShardMap"]
 
 
 @dataclass(frozen=True)
@@ -176,166 +154,3 @@ class HostShardMap:
                 nxt.append(slots[-1])
             slots = nxt
         return slots[0]
-
-
-@dataclass
-class ShardingRules:
-    """Ordered (regex, template) rules + logical→mesh axis resolution."""
-
-    rules: list  # [(compiled_regex, tuple_of_logical_axes_or_None)]
-    axis_map: dict  # logical -> mesh axis name (str) | tuple | None
-    default: tuple = ()
-
-    def resolve(self, template) -> P:
-        out = []
-        for ax in template:
-            m = self.axis_map.get(ax, None) if ax is not None else None
-            # Canonicalize 1-tuples to the bare axis name: newer jax does
-            # this inside PartitionSpec; older versions compare unequal.
-            if isinstance(m, tuple) and len(m) == 1:
-                m = m[0]
-            out.append(m)
-        return P(*out)
-
-    def spec_for_path(self, path: str) -> P:
-        for rx, template in self.rules:
-            if rx.search(path):
-                return self.resolve(template)
-        return P()
-
-    def tree_specs(self, tree):
-        """PartitionSpec pytree matching ``tree`` by leaf path."""
-        flat = jax.tree_util.tree_flatten_with_path(tree)
-        specs = []
-        for pathkeys, leaf in flat[0]:
-            path = "/".join(str(getattr(p, "key", getattr(p, "idx", p)))
-                            for p in pathkeys)
-            spec = self.spec_for_path(path)
-            # Guard: spec rank must not exceed leaf rank.
-            if len(spec) > getattr(leaf, "ndim", 0):
-                spec = P(*list(spec)[: getattr(leaf, "ndim", 0)])
-            specs.append(spec)
-        return jax.tree_util.tree_unflatten(flat[1], specs)
-
-
-def _compile(rules):
-    return [(re.compile(rx), tpl) for rx, tpl in rules]
-
-
-# Leaf name conventions (see repro/models/): layer-stacked leaves live under
-# "layers/" with leading L dim; embeddings and final norms are unstacked.
-#   embed [V,D] · lm_head [D,V] · layers/wq|wk|wv [L,D,H*hd] · layers/wo
-#   [L,H*hd,D] · layers/w_gate|w_up [L,D,F] · layers/w_down [L,F,D] ·
-#   layers/moe_{gate,up,down} [L,E,...] · layers/router [L,D,E] ·
-#   layers/mamba_* · norms/biases replicated.
-def make_sharding_rules(policy: str, mesh: Mesh, *, fl_axes=("data",),
-                        extra_rules=None) -> dict:
-    """Build rules for params, round arrays, and serve-time caches.
-
-    Returns dict with 'params', 'arrays', 'kv' ShardingRules.
-    """
-    axes = set(mesh.axis_names)
-    fl_axes = tuple(a for a in fl_axes if a in axes)
-    # FSDP must not reuse an FL-worker axis: the worker vmap already owns it
-    # (spmd_axis_name), and double-booking forces XLA to replicate params.
-    fsdp_axes = tuple(a for a in ("pod", "data")
-                      if a in axes and a not in fl_axes)
-
-    if policy == "tp":
-        # small-arch regime: workers hold whole clients.  Experts are NOT
-        # expert-parallel (counts like granite's 40 need not divide the TP
-        # axis); instead the per-expert hidden dim F carries the TP shard —
-        # same math as dense Megatron MLP, valid for any expert count.
-        axis_map = {"tp": "model", "fsdp": None, "ep": None,
-                    "moe_f": "model",
-                    "fl": fl_axes if fl_axes else None}
-    elif policy == "fsdp_tp":
-        # large-arch regime: experts sharded over the model axis (EP);
-        # per-expert F stays whole (one expert's GEMM on one chip group).
-        axis_map = {"tp": "model", "fsdp": fsdp_axes or None, "ep": "model",
-                    "moe_f": None,
-                    "fl": fl_axes if fl_axes else None}
-    elif policy == "fsdp_tp_ep":
-        axis_map = {"tp": "model", "fsdp": fsdp_axes or None, "ep": "model",
-                    "moe_f": None,
-                    "fl": fl_axes if fl_axes else None}
-    elif policy == "fsdp_tp_noep":
-        # experts NOT expert-parallel: every expert's weights sharded over
-        # (data, model) like a dense layer — dispatch stays node-local,
-        # the per-expert GEMMs psum over the contracted shards instead of
-        # all-to-all'ing tokens (the §Perf alternative for top-8 routing,
-        # where EP moves every token 2k times per layer).
-        axis_map = {"tp": "model", "fsdp": fsdp_axes or None, "ep": None,
-                    "moe_f": "model",
-                    "fl": fl_axes if fl_axes else None}
-    else:
-        raise ValueError(f"unknown sharding policy {policy!r}")
-
-    # -- parameters ---------------------------------------------------------
-    param_rules = _compile((extra_rules or []) + [
-        # embeddings / heads
-        (r"(^|/)embed$",        ("tp", "fsdp")),         # [V, D]
-        (r"(^|/)lm_head$",      ("fsdp", "tp")),         # [D, V]
-        (r"(^|/)pos_embed$",    (None, None)),
-        (r"(^|/)patch_proj$",   ("fsdp", "tp")),         # [d_vit, D]
-        # attention biases (vector, head dim): tp-sharded like their matrices
-        (r"/x?b[qkv]$",         (None, "tp")),
-        (r"/x?bo$|/b_down$",    (None,)),                # follows wo row-shard
-        (r"/b_up$",             (None, "tp")),
-        # attention (layer-stacked: leading L dim)
-        (r"/wq$|/wk$|/wv$",     (None, "fsdp", "tp")),   # [L, D, H*hd]
-        (r"/wo$",               (None, "tp", "fsdp")),   # [L, H*hd, D]
-        # dense mlp
-        (r"/w_gate$|/w_up$",    (None, "fsdp", "tp")),   # [L, D, F]
-        (r"/w_down$",           (None, "tp", "fsdp")),   # [L, F, D]
-        # MoE (expert dim second): [L, E, D, F] / [L, E, F, D]
-        (r"/moe_gate$|/moe_up$", (None, "ep", "fsdp", "moe_f")),
-        (r"/moe_down$",          (None, "ep", "moe_f", "fsdp")),
-        (r"/router$",            (None, "fsdp", None)),  # [L, D, E]
-        # mamba
-        (r"/mamba_in$",         (None, "fsdp", "tp")),   # [L, D, Dinner+...]
-        (r"/mamba_out$",        (None, "tp", "fsdp")),   # [L, Dinner, D]
-        (r"/mamba_conv$",       (None, None, "tp")),     # [L, K, Dconv]
-        (r"/mamba_(A|dt_bias|D)$", (None, "tp")),        # [L, Hm]
-        # biases / norms / scalars: replicated
-        (r"norm|bias|scale|ln_",  ()),
-    ])
-
-    # -- round arrays [W, P, S, b, ...]: shard W over FL axes, batch over none
-    array_rules = _compile([
-        (r".*", ("fl",)),
-    ])
-
-    # -- serve-time cache [p{i}][leaf], leaves lead with the n_periods dim:
-    #    k/v/xk/xv [np, B, T, Hkv, hd]  — batch over data(+pod), cache length
-    #    over model (flash-decode partial-softmax memory balance; Hkv is
-    #    often smaller than the model axis, so heads cannot carry TP here);
-    #    conv [np, B, k-1, C] — conv channels over model;
-    #    ssm  [np, B, H, p, n] — ssm heads over model.
-    kv_rules = _compile([
-        (r"/(k|v|xk|xv)$", (None, "kvbatch", "kvseq", None, None)),
-        (r"/conv$",        (None, "kvbatch", None, "tp")),
-        (r"/ssm$",         (None, "kvbatch", "tp", None, None)),
-        (r".*", ("kvbatch",)),
-    ])
-    kv_axis_map = dict(axis_map)
-    kv_axis_map.update({
-        "kvbatch": tuple(a for a in ("pod", "data") if a in axes) or None,
-        "kvseq": "model",
-    })
-
-    return {
-        "params": ShardingRules(rules=param_rules, axis_map=axis_map),
-        "arrays": ShardingRules(rules=array_rules, axis_map=axis_map),
-        "kv": ShardingRules(rules=kv_rules, axis_map=kv_axis_map),
-        "policy": policy,
-    }
-
-
-def spec_for_tree(rules: ShardingRules, tree):
-    return rules.tree_specs(tree)
-
-
-def named_shardings(mesh: Mesh, spec_tree):
-    return jax.tree.map(lambda s: NamedSharding(mesh, s), spec_tree,
-                        is_leaf=lambda x: isinstance(x, P))

@@ -122,7 +122,7 @@ def _make_lane_scan(loss_fn, optimizer, *, agg_impl: str = "xla",
                 grads, _ = clip_by_global_norm(grads, grad_clip)
             updates, new_opt = optimizer.update(grads, opt_state, theta)
             # mask cast per-leaf: bf16 * f32-mask would promote a whole
-            # param-shaped temporary to f32 (observed in the dry-run HLO)
+            # param-shaped temporary to f32 (observed in the lowered HLO)
             theta = apply_updates(
                 theta, jax.tree.map(lambda u: u * m.astype(u.dtype), updates))
             # Masked steps keep the old optimizer state (exact no-op).
@@ -160,19 +160,11 @@ def _make_lane_scan(loss_fn, optimizer, *, agg_impl: str = "xla",
 
 
 def make_round_step(loss_fn, optimizer, *, agg_impl: str = "xla",
-                    grad_clip: float | None = None,
-                    worker_spmd_axes=None):
+                    grad_clip: float | None = None):
     """Build the jittable federated round function.
 
     loss_fn(params, batch) -> scalar loss (batch is a dict of arrays for one
     local step).  optimizer is a repro.optim.Optimizer.
-
-    ``worker_spmd_axes``: mesh axis name (or tuple) the FL-worker dim W is
-    sharded over.  Passed as ``spmd_axis_name`` to the worker vmap so every
-    per-worker intermediate — the evolving client parameters, optimizer
-    state, and partial aggregate — is *constrained* to shard its W dim over
-    those axes instead of relying on XLA propagation (which may otherwise
-    replicate W copies of the client model on every chip).
     """
     lane_scan = _make_lane_scan(loss_fn, optimizer, agg_impl=agg_impl,
                                 grad_clip=grad_clip)
@@ -180,8 +172,9 @@ def make_round_step(loss_fn, optimizer, *, agg_impl: str = "xla",
     def round_step(global_params, batches, step_mask, boundary, weight):
         W, Pn = step_mask.shape[:2]
         if W == 1 and Pn == 1:
-            # single-worker fast path: no vmap wrappers, so manual-collective
-            # layers (shard_map EP dispatch, §Perf B3) can live inside.
+            # single-worker fast path: the lane scan runs without vmap
+            # wrappers.  kanana2-c8 (W1 x P1) runs here; whether the vmap
+            # branch below runs it as fast is unmeasured (ROADMAP D15).
             squeezed = jax.tree.map(lambda x: x[0, 0], batches)
             partial, loss1, counts = lane_scan(global_params, squeezed,
                                                step_mask[0, 0], boundary[0, 0],
@@ -189,11 +182,10 @@ def make_round_step(loss_fn, optimizer, *, agg_impl: str = "xla",
             partials = jax.tree.map(lambda x: x[None, None], partial)
             lane_losses = loss1[None, None]
         else:
-            # vmap lanes over P then workers over W; params broadcast
-            # (replicated or FSDP-sharded — the sharding rules decide).
+            # vmap lanes over P then workers over W; every lane starts from
+            # the same global params (broadcast, in_axes None).
             per_lane = jax.vmap(lane_scan, in_axes=(None, 0, 0, 0, 0))
-            per_worker = jax.vmap(per_lane, in_axes=(None, 0, 0, 0, 0),
-                                  spmd_axis_name=worker_spmd_axes)
+            per_worker = jax.vmap(per_lane, in_axes=(None, 0, 0, 0, 0))
             partials, lane_losses, counts = per_worker(
                 global_params, batches, step_mask, boundary, weight)
             counts = jax.tree.map(jnp.sum, counts)

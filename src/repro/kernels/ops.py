@@ -2,8 +2,7 @@
 
 These adapt model-layer layouts to kernel layouts (transpose/pad), pick
 block sizes the TPU compiler accepts, and fall back to interpret mode
-off-TPU so the same call sites work in tests (CPU), dry-runs, and on real
-hardware.
+off-TPU so the same call sites work in tests (CPU) and on real hardware.
 
 Row blocks follow the TPU tiling rule: a block's second-minor dim is a
 multiple of the dtype's sublane tile (8 rows of f32; 32 of int8), or equals

@@ -1,2 +1,2 @@
-"""Launch stack: mesh construction, per-cell planning, dry-run driver,
-roofline analysis, and the train/serve entry points."""
+"""Launch stack: the train entry point, the compile cache, the mesh path's
+device binding, the process-per-host harness and an HLO cost walker."""

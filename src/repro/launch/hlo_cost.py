@@ -17,8 +17,7 @@ yielding honest per-device totals:
                   exactly XLA's "one HBM pass per fusion" contract);
 * ``collectives`` — every all-gather/all-reduce/reduce-scatter/all-to-all/
                   collective-permute with its payload bytes, replica-group
-                  size and multiplicity (ring wire cost model applied by the
-                  caller in roofline.py).
+                  size and multiplicity.
 
 Trip counts come from the loop-condition computation: the largest integer
 literal compared against the induction variable (exactly how lax.scan

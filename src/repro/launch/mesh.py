@@ -1,39 +1,15 @@
-"""Production mesh construction.
+"""Device binding of the mesh path's FL-worker shards.
 
-Functions, not module-level constants, so importing this module never touches
-jax device state (the dry-run driver sets XLA_FLAGS before any jax import).
-
-Mesh layout (TPU v5e-class pods of 256 chips):
-
-  single-pod : (16, 16)    axes ("data", "model")
-  multi-pod  : (2, 16, 16) axes ("pod", "data", "model")
-
-FL semantics on top of the mesh: the *worker* axes (pod and/or data) index
-Pollen's FL workers; the model axis carries TP/EP; FSDP uses (pod, data).
+The worker axes of a mesh (``pod`` and/or ``data``) index Pollen's FL
+workers; a ``model`` axis, where present, lies within a shard.  Without a
+mesh, shards cycle over ``jax.devices()``.
 """
 
 from __future__ import annotations
 
 import jax
-from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "make_test_mesh", "axis_sizes",
-           "fl_shard_devices", "fl_combine_topology"]
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-
-
-def make_test_mesh(shape=(1, 1), axes=("data", "model")):
-    """Tiny mesh over however many (host) devices exist — smoke tests."""
-    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-
-
-def axis_sizes(mesh) -> dict:
-    return dict(zip(mesh.axis_names, mesh.devices.shape))
+__all__ = ["fl_shard_devices", "fl_combine_topology"]
 
 
 def fl_shard_devices(n_shards: int, *, mesh=None, fl_axes=("pod", "data")):

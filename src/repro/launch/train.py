@@ -4,8 +4,8 @@ Composes the full stack: federated dataset → cohort sampler → placement
 (RR / BB / LB) → worker pool (with optional failure injection) → jitted
 round step (partial aggregation) → telemetry → time-model refit →
 checkpointing.  Works for the paper's four FL tasks and for any assigned
-LM architecture (reduced or preset scale for CPU; the full configs are
-exercised by the dry-run).
+LM architecture (reduced or preset scale for CPU; tests/test_archs.py
+traces the full configs' round step).
 
 Examples:
     PYTHONPATH=src python -m repro.launch.train --task sr --rounds 30

@@ -24,9 +24,7 @@ def make_loss_fn(cfg):
 
 
 def make_batch_spec(cfg, *, batch: int, seq_len: int):
-    """Host-side shapes/dtypes of one training micro-batch for this arch.
-
-    Mirrors ``launch.plan.input_specs`` but for concrete small batches
+    """Host-side shapes/dtypes of one training micro-batch for this arch
     (smoke tests, the FL engine's synthetic federated data)."""
     import numpy as np
 

@@ -2,11 +2,10 @@
 
 Everything is a pure function over explicit parameter dicts (no flax), with
 layer-stacked parameters (leading L dim) consumed by ``lax.scan`` so HLO size
-stays O(1) in depth — essential for the 94-layer dry-run compiles.
+stays O(1) in depth.
 
-Naming follows the sharding convention in ``repro.distributed.sharding``:
-``wq/wk/wv/wo``, ``w_gate/w_up/w_down``, ``moe_gate/moe_up/moe_down``,
-``router``, ``mamba_*``, ``*norm*``.
+Parameter names: ``wq/wk/wv/wo``, ``w_gate/w_up/w_down``,
+``moe_gate/moe_up/moe_down``, ``router``, ``mamba_*``, ``*norm*``.
 
 Attention supports three implementations (the §Perf knob):
   * 'dense'   — materialized scores (baseline; XLA cost model sees it all)
@@ -25,7 +24,7 @@ import jax.numpy as jnp
 
 __all__ = [
     "rms_norm", "rope", "rope_interleaved", "gqa_attention", "mla_attention",
-    "swiglu", "gelu_mlp", "moe_layer", "grouped_matmul", "route_topk",
+    "swiglu", "gelu_mlp", "moe_layer_3d", "grouped_matmul", "route_topk",
     "moe_dropless", "masked_tied_xent", "xent_chunk_rows",
     "dense_init", "norm_init", "causal_scores_mask", "decode_attention",
 ]
@@ -121,21 +120,11 @@ def _dense_attention(q, k, v, *, causal: bool, q_offset: int = 0):
 
 
 def _chunked_attention(q, k, v, *, causal: bool, q_chunk: int = 512,
-                       q_offset: int = 0, repeat_kv: bool = False):
+                       q_offset: int = 0):
     """Online-softmax over query chunks: flash-attention dataflow in pure
-    JAX.  Memory O(s·q_chunk) instead of O(s²).
-
-    ``repeat_kv`` materializes k/v per q-head first (g → 1).  Under tensor
-    parallelism this keeps the head dim evenly sharded: the [hkv, g] split of
-    a TP-sharded head dim does not tile when TP > Hkv, which forces XLA to
-    re-gather; repeated kv heads shard exactly like q heads.
-    """
+    JAX.  Memory O(s·q_chunk) instead of O(s²)."""
     b, s, hq, hd = q.shape
     t, hkv = k.shape[1], k.shape[2]
-    if repeat_kv and hkv != hq:
-        k = jnp.repeat(k, hq // hkv, axis=2)
-        v = jnp.repeat(v, hq // hkv, axis=2)
-        hkv = hq
     g = hq // hkv
     scale = 1.0 / math.sqrt(hd)
     q_chunk = min(q_chunk, s)
@@ -167,13 +156,12 @@ def _chunked_attention(q, k, v, *, causal: bool, q_chunk: int = 512,
 
 
 def gqa_attention(q, k, v, *, causal: bool = True, impl: str = "dense",
-                  q_offset: int = 0, q_chunk: int = 512,
-                  repeat_kv: bool = False):
+                  q_offset: int = 0, q_chunk: int = 512):
     if impl == "dense":
         return _dense_attention(q, k, v, causal=causal, q_offset=q_offset)
     if impl == "chunked":
         return _chunked_attention(q, k, v, causal=causal, q_offset=q_offset,
-                                  q_chunk=q_chunk, repeat_kv=repeat_kv)
+                                  q_chunk=q_chunk)
     if impl == "pallas":
         from repro.kernels import ops as kops
         return kops.flash_attention(q, k, v, causal=causal)
@@ -243,75 +231,79 @@ def gelu_mlp(x, w_up, b_up, w_down, b_down):
 # --------------------------------------------------------------------------
 # Mixture of Experts
 # --------------------------------------------------------------------------
-def moe_layer(x, router_w, moe_gate, moe_up, moe_down, *, top_k: int,
-              capacity_factor: float = 1.25, impl: str = "einsum",
-              ep_shard=None, token_chunk: int = 0, remat: bool = False):
-    """Top-k routed MoE over flattened tokens (see _moe_dispatch).
-
-    ``token_chunk`` > 0 processes tokens in blocks of that size via a scan:
-    dispatch/capacity buffers scale with the chunk, not the full T — the
-    fix for prefill-scale T (1M tokens → 60 GiB replicated buffers).
-    Routing stays per-chunk (capacity C = cf·k·Tc/E per chunk), which
-    slightly *loosens* dropping vs global routing — same spirit as
-    per-device capacity in EP systems.
-    """
-    T, D = x.shape
-    if token_chunk and T > token_chunk:
-        if T % token_chunk:
-            pad = token_chunk - T % token_chunk
-            x = jnp.concatenate([x, jnp.zeros((pad, D), x.dtype)])
-        nc = x.shape[0] // token_chunk
-
-        def body(carry, xc):
-            out, aux = _moe_dispatch(xc, router_w, moe_gate, moe_up,
-                                     moe_down, top_k=top_k,
-                                     capacity_factor=capacity_factor,
-                                     impl=impl, ep_shard=ep_shard)
-            return carry + aux, out
-
-        fn = jax.checkpoint(body, prevent_cse=False) if remat else body
-        aux, outs = jax.lax.scan(fn, jnp.zeros((), jnp.float32),
-                                 x.reshape(nc, token_chunk, D))
-        return outs.reshape(-1, D)[:T], aux / nc
-    return _moe_dispatch(x, router_w, moe_gate, moe_up, moe_down,
-                         top_k=top_k, capacity_factor=capacity_factor,
-                         impl=impl, ep_shard=ep_shard)
-
-
 def moe_layer_3d(x3, router_w, moe_gate, moe_up, moe_down, *, top_k: int,
-                 capacity_factor: float = 1.25, impl: str = "einsum",
-                 ep_shard=None, seq_chunk: int = 0, remat: bool = False):
-    """Batched MoE over [b, s, D] with sequence-chunked dispatch.
+                 capacity_factor: float = 1.25, impl: str = "einsum"):
+    """Capacity-routed top-k MoE over the b·s tokens of x3 [b, s, D]; a
+    token past its expert's capacity C = cf·k·T/E gets no output from it.
 
-    Chunking along s (batch kept as a real dim) keeps the flattened token
-    dim sharded over the batch/data axis only — chunking a flattened
-    (data×model)-sharded token dim instead makes XLA materialize replicated
-    chunk stacks (observed 8 GiB f32 buffers in the jamba dry-run).
+    router_w: [D, E]; moe_gate/up: [E, D, F]; moe_down: [E, F, D].
+    impl='einsum' — Mesh-TF style one-hot dispatch/combine einsums (baseline).
+    impl='scatter' — gather/scatter dispatch (beyond-paper optimization: the
+    dispatch flops drop from O(T·E·C·D) to O(T·k·D)).
+    Returns (out [b, s, D], aux) with aux = load-balancing loss ingredients.
     """
     b, s, D = x3.shape
-    if not seq_chunk or s <= seq_chunk:
-        out, aux = _moe_dispatch(x3.reshape(b * s, D), router_w, moe_gate,
-                                 moe_up, moe_down, top_k=top_k,
-                                 capacity_factor=capacity_factor, impl=impl,
-                                 ep_shard=ep_shard)
-        return out.reshape(b, s, D), aux
-    pad = (-s) % seq_chunk
-    if pad:
-        x3 = jnp.pad(x3, ((0, 0), (0, pad), (0, 0)))
-    nc = x3.shape[1] // seq_chunk
-    xs = jnp.moveaxis(x3.reshape(b, nc, seq_chunk, D), 1, 0)
+    x = x3.reshape(b * s, D)
+    T = b * s
+    E = router_w.shape[-1]
+    logits = (x @ router_w).astype(jnp.float32)            # [T, E]
+    probs = jax.nn.softmax(logits, axis=-1)
+    gate_vals, gate_idx = jax.lax.top_k(probs, top_k)      # [T, k]
+    gate_vals = gate_vals / jnp.maximum(
+        gate_vals.sum(-1, keepdims=True), 1e-9)            # renormalize top-k
+    C = max(1, int(capacity_factor * top_k * T / E))
 
-    def body(carry, xc):                          # xc [b, sc, D]
-        out, aux = _moe_dispatch(xc.reshape(b * seq_chunk, D), router_w,
-                                 moe_gate, moe_up, moe_down, top_k=top_k,
-                                 capacity_factor=capacity_factor, impl=impl,
-                                 ep_shard=ep_shard)
-        return carry + aux, out.reshape(b, seq_chunk, D)
+    # position of each (token, k) within its expert's capacity buffer
+    onehot = jax.nn.one_hot(gate_idx, E, dtype=jnp.int32)       # [T, k, E]
+    flat_onehot = onehot.reshape(T * top_k, E)
+    pos_in_expert = jnp.cumsum(flat_onehot, axis=0) * flat_onehot - 1
+    pos_in_expert = pos_in_expert.reshape(T, top_k, E)
+    within_cap = (pos_in_expert >= 0) & (pos_in_expert < C)
 
-    fn = jax.checkpoint(body, prevent_cse=False) if remat else body
-    aux, outs = jax.lax.scan(fn, jnp.zeros((), jnp.float32), xs)
-    out = jnp.moveaxis(outs, 0, 1).reshape(b, nc * seq_chunk, D)[:, :s]
-    return out, aux / nc
+    if impl == "einsum":
+        cap_oh = jax.nn.one_hot(jnp.where(within_cap, pos_in_expert, -1), C,
+                                dtype=x.dtype)                  # [T,k,E,C]
+        dispatch = cap_oh                                        # bool-ish
+        combine = cap_oh * gate_vals[..., None, None].astype(x.dtype)
+        expert_in = jnp.einsum("tkec,td->ecd", dispatch, x)      # [E,C,D]
+        h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", expert_in, moe_gate))
+        h = h * jnp.einsum("ecd,edf->ecf", expert_in, moe_up)
+        expert_out = jnp.einsum("ecf,efd->ecd", h, moe_down)     # [E,C,D]
+        out = jnp.einsum("tkec,ecd->td", combine, expert_out)
+    elif impl == "scatter":
+        # Scatter tokens into [E, C, D] buffers, batched expert matmul,
+        # gather back.  No T·E·C einsums.  scatter-ADD, not set: slots are
+        # unique so the math is identical, but add's transpose is a plain
+        # gather — scatter-set under vmap+AD lowers to a select-based
+        # emulation with element-granular index tensors (observed 10-100x
+        # memory blowup on granite).
+        flat_expert = gate_idx.reshape(-1)                       # [T*k]
+        flat_pos = jnp.take_along_axis(
+            pos_in_expert.reshape(T * top_k, E),
+            flat_expert[:, None], axis=1)[:, 0]                  # [T*k]
+        ok = (flat_pos >= 0) & (flat_pos < C)
+        slot = jnp.where(ok, flat_expert * C + flat_pos, E * C)  # overflow row
+        buf = jnp.zeros((E * C + 1, D), x.dtype).at[slot].add(
+            jnp.repeat(x, top_k, axis=0), mode="drop",
+            unique_indices=True)
+        expert_in = buf[:-1].reshape(E, C, D)
+        h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", expert_in, moe_gate))
+        h = h * jnp.einsum("ecd,edf->ecf", expert_in, moe_up)
+        expert_out = jnp.einsum("ecf,efd->ecd", h, moe_down)
+        expert_out = expert_out.reshape(E * C, D)
+        expert_out = jnp.concatenate(
+            [expert_out, jnp.zeros((1, D), x.dtype)], axis=0)
+        gathered = expert_out[jnp.where(ok, slot, E * C)]        # [T*k, D]
+        out = (gathered.reshape(T, top_k, D)
+               * gate_vals[..., None].astype(x.dtype)).sum(axis=1)
+    else:
+        raise ValueError(f"unknown moe impl {impl!r}")
+
+    # Aux loss ingredients (Switch-style load balance).
+    density = jnp.mean(jax.nn.one_hot(gate_idx[:, 0], E, dtype=jnp.float32), 0)
+    density_proxy = jnp.mean(probs, axis=0)
+    aux = E * jnp.sum(density * density_proxy)
+    return out.astype(x.dtype).reshape(b, s, D), aux
 
 
 _WGRAD_DIMS = jax.lax.RaggedDotDimensionNumbers(
@@ -535,80 +527,3 @@ def masked_tied_xent(h, embed, labels, mask, *, rate: float):
     counts = {"head_rows": n.astype(jnp.float32),
               "head_buffer_rows": (_xent_trips(n, C) * C).astype(jnp.float32)}
     return loss, counts
-
-
-def _moe_dispatch(x, router_w, moe_gate, moe_up, moe_down, *, top_k: int,
-                  capacity_factor: float = 1.25, impl: str = "einsum",
-                  ep_shard=None):
-    """Top-k routed MoE over flattened tokens.
-
-    x: [T, D]; router_w: [D, E]; moe_gate/up: [E, D, F]; moe_down: [E, F, D].
-    impl='einsum' — Mesh-TF style one-hot dispatch/combine einsums (baseline).
-    impl='scatter' — gather/scatter dispatch (beyond-paper optimization: the
-    dispatch flops drop from O(T·E·C·D) to O(T·k·D)).
-    Returns (out [T, D], aux) with aux = load-balancing loss ingredients.
-    """
-    T, D = x.shape
-    E = router_w.shape[-1]
-    logits = (x @ router_w).astype(jnp.float32)            # [T, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, top_k)      # [T, k]
-    gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(-1, keepdims=True), 1e-9)            # renormalize top-k
-    C = max(1, int(capacity_factor * top_k * T / E))
-
-    # position of each (token, k) within its expert's capacity buffer
-    onehot = jax.nn.one_hot(gate_idx, E, dtype=jnp.int32)       # [T, k, E]
-    flat_onehot = onehot.reshape(T * top_k, E)
-    pos_in_expert = jnp.cumsum(flat_onehot, axis=0) * flat_onehot - 1
-    pos_in_expert = pos_in_expert.reshape(T, top_k, E)
-    within_cap = (pos_in_expert >= 0) & (pos_in_expert < C)
-
-    if impl == "einsum":
-        cap_oh = jax.nn.one_hot(jnp.where(within_cap, pos_in_expert, -1), C,
-                                dtype=x.dtype)                  # [T,k,E,C]
-        dispatch = cap_oh                                        # bool-ish
-        combine = cap_oh * gate_vals[..., None, None].astype(x.dtype)
-        expert_in = jnp.einsum("tkec,td->ecd", dispatch, x)      # [E,C,D]
-        h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", expert_in, moe_gate))
-        h = h * jnp.einsum("ecd,edf->ecf", expert_in, moe_up)
-        expert_out = jnp.einsum("ecf,efd->ecd", h, moe_down)     # [E,C,D]
-        out = jnp.einsum("tkec,ecd->td", combine, expert_out)
-    elif impl == "scatter":
-        # Scatter tokens into [E, C, D] buffers, batched expert matmul,
-        # gather back.  No T·E·C einsums.  scatter-ADD, not set: slots are
-        # unique so the math is identical, but add's transpose is a plain
-        # gather — scatter-set under vmap+AD lowers to a select-based
-        # emulation with element-granular index tensors (observed 10-100x
-        # memory blowup in the granite dry-run).
-        flat_expert = gate_idx.reshape(-1)                       # [T*k]
-        flat_pos = jnp.take_along_axis(
-            pos_in_expert.reshape(T * top_k, E),
-            flat_expert[:, None], axis=1)[:, 0]                  # [T*k]
-        ok = (flat_pos >= 0) & (flat_pos < C)
-        slot = jnp.where(ok, flat_expert * C + flat_pos, E * C)  # overflow row
-        buf = jnp.zeros((E * C + 1, D), x.dtype).at[slot].add(
-            jnp.repeat(x, top_k, axis=0), mode="drop",
-            unique_indices=True)
-        expert_in = buf[:-1].reshape(E, C, D)
-        if ep_shard is not None:
-            expert_in = ep_shard(expert_in)     # [E('model'), C, D]
-        h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", expert_in, moe_gate))
-        h = h * jnp.einsum("ecd,edf->ecf", expert_in, moe_up)
-        expert_out = jnp.einsum("ecf,efd->ecd", h, moe_down)
-        if ep_shard is not None:
-            expert_out = ep_shard(expert_out)
-        expert_out = expert_out.reshape(E * C, D)
-        expert_out = jnp.concatenate(
-            [expert_out, jnp.zeros((1, D), x.dtype)], axis=0)
-        gathered = expert_out[jnp.where(ok, slot, E * C)]        # [T*k, D]
-        out = (gathered.reshape(T, top_k, D)
-               * gate_vals[..., None].astype(x.dtype)).sum(axis=1)
-    else:
-        raise ValueError(f"unknown moe impl {impl!r}")
-
-    # Aux loss ingredients (Switch-style load balance).
-    density = jnp.mean(jax.nn.one_hot(gate_idx[:, 0], E, dtype=jnp.float32), 0)
-    density_proxy = jnp.mean(probs, axis=0)
-    aux = E * jnp.sum(density * density_proxy)
-    return out.astype(x.dtype), aux

@@ -6,7 +6,7 @@ the smallest repeating pattern of layer kinds (dense archs: 1; jamba: 8 =
 lcm(attention-every-8, moe-every-2)); parameters are stacked per
 period-position with a leading ``n_periods`` dim and the stack runs as a
 ``lax.scan`` over periods, so HLO size is O(period), not O(n_layers) — this
-is what keeps the 94-layer qwen3-moe dry-run compile tractable.
+is what keeps the trace of the 94-layer qwen3-moe tractable.
 
 Block kinds (cfg-driven):
     mixer: 'attn' (GQA + RoPE [+qk-norm] [+cross-attn]) | 'mla' (latent
@@ -29,9 +29,7 @@ Entry points:
     prefill(params, batch, cfg)               -> (logits_last, cache)
     decode_step(params, cache, tokens, pos, cfg) -> (logits, cache)
 
-All functions are pure and jit/vmap/scan-safe.  Sharding is injected from
-outside via ``cfg.act_shard`` hooks (with_sharding_constraint partials); the
-model itself never imports mesh machinery.
+All functions are pure and jit/vmap/scan-safe.
 """
 
 from __future__ import annotations
@@ -45,7 +43,8 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig
 from repro.models import ssd as ssdlib
 from repro.models.layers import (decode_attention, dense_init, gqa_attention,
-                                 mla_attention, moe_dropless, rms_norm, rope)
+                                 mla_attention, moe_dropless, moe_layer_3d,
+                                 rms_norm, rope)
 
 __all__ = ["init_params", "forward", "loss_fn", "loss_and_counts",
            "init_cache", "prefill", "decode_step", "layer_plan", "LayerKind",
@@ -310,7 +309,7 @@ def _attn_out(p, attn, cfg: ArchConfig, *, prefix: str = ""):
 def _attn_body(p, x, cfg: ArchConfig, *, causal: bool, positions=None,
                norm_key: str = "attn_norm"):
     """Full-sequence attention sub-block (training / prefill / encoder)."""
-    h = cfg.act_gather(rms_norm(x, p[norm_key], eps=cfg.norm_eps))
+    h = rms_norm(x, p[norm_key], eps=cfg.norm_eps)
     q, k, v = _project_qkv(p, h, cfg)
     if cfg.rope:
         if positions is None:
@@ -318,8 +317,7 @@ def _attn_body(p, x, cfg: ArchConfig, *, causal: bool, positions=None,
         q = rope(q, positions, theta=cfg.rope_theta)
         k = rope(k, positions, theta=cfg.rope_theta)
     attn = gqa_attention(q, k, v, causal=causal, impl=cfg.attn_impl,
-                         q_chunk=cfg.attn_q_chunk,
-                         repeat_kv=cfg.attn_repeat_kv)
+                         q_chunk=cfg.attn_q_chunk)
     return _attn_out(p, attn, cfg), (k, v)
 
 
@@ -353,7 +351,6 @@ def _mlp_body(p, x, cfg: ArchConfig, kind: str, *, norm_key: str = "mlp_norm"):
     """Returns (out, stats): ``stats`` holds the block's auxiliary loss
     (``aux``, capacity-routed MoE) or its expert row counts (dropless)."""
     h = rms_norm(x, p[norm_key], eps=cfg.norm_eps) if norm_key else x
-    h = cfg.act_gather(h)
     if kind == "swiglu":
         z = jax.nn.silu(h @ p["w_gate"]) * (h @ p["w_up"])
         return z @ p["w_down"], {}
@@ -377,23 +374,16 @@ def _mlp_body(p, x, cfg: ArchConfig, kind: str, *, norm_key: str = "mlp_norm"):
                 out = out + (jax.nn.silu(h @ p["shared_gate"])
                              * (h @ p["shared_up"])) @ p["shared_down"]
             return out, counts
-        if cfg.moe_dispatch is not None:   # §Perf B3: manual EP (shard_map)
-            out, aux = cfg.moe_dispatch(
-                h, p["router"], p["moe_gate"], p["moe_up"], p["moe_down"],
-                top_k=cfg.top_k, capacity_factor=cfg.capacity_factor)
-            return out, {"aux": aux}
-        from repro.models.layers import moe_layer_3d
         out, aux = moe_layer_3d(h, p["router"], p["moe_gate"], p["moe_up"],
                                 p["moe_down"], top_k=cfg.top_k,
                                 capacity_factor=cfg.capacity_factor,
-                                impl=cfg.moe_impl, ep_shard=cfg.act_shard_moe,
-                                seq_chunk=cfg.moe_seq_chunk, remat=cfg.remat)
+                                impl=cfg.moe_impl)
         return out, {"aux": aux}
     raise ValueError(kind)
 
 
 def _mamba_body(p, x, cfg: ArchConfig, *, return_state: bool = False):
-    h = cfg.act_gather(rms_norm(x, p["mamba_norm"], eps=cfg.norm_eps))
+    h = rms_norm(x, p["mamba_norm"], eps=cfg.norm_eps)
     return ssdlib.mamba2_mixer(
         p, h, head_dim=cfg.ssm_head_dim, n_groups=cfg.ssm_groups,
         d_state=cfg.ssm_state, chunk=cfg.ssd_chunk, impl=cfg.ssd_impl,
@@ -408,7 +398,7 @@ def _apply_block(p, x, cfg: ArchConfig, kind: LayerKind, *, causal: bool,
     if kind.mixer == "mla":
         if collect_kv:
             raise NotImplementedError("no serving cache for latent attention")
-        h = cfg.act_gather(rms_norm(x, p["attn_norm"], eps=cfg.norm_eps))
+        h = rms_norm(x, p["attn_norm"], eps=cfg.norm_eps)
         if positions is None:
             positions = jnp.arange(x.shape[1])[None, :]
         attn = mla_attention(
@@ -448,7 +438,6 @@ def _apply_block(p, x, cfg: ArchConfig, kind: LayerKind, *, causal: bool,
         if kind.mlp != "none":
             mlp_out, aux = _mlp_body(p, x, cfg, kind.mlp)
             x = x + mlp_out
-    x = cfg.act_shard(x)
     return x, aux, contrib
 
 
@@ -582,7 +571,6 @@ def loss_and_counts(params, batch, cfg: ArchConfig):
         hc, lc, mc = inp                            # [b,chunk,D],[b,chunk]
         logits = jnp.einsum("bsd,dv->bsv", hc, w,
                             preferred_element_type=jnp.float32)
-        logits = cfg.act_shard_logits(logits)
         if cfg.padded_vocab != cfg.vocab_size:      # mask vocab padding
             vmask = jnp.arange(cfg.padded_vocab) < cfg.vocab_size
             logits = jnp.where(vmask, logits, -1e30)
@@ -768,7 +756,6 @@ def decode_step(params, cache, tokens, pos, cfg: ArchConfig):
                 if kind.mlp != "none":
                     mlp_out, _ = _mlp_body(p, x, cfg, kind.mlp)
                     x = x + mlp_out
-            x = cfg.act_shard(x)
             new_cache[f"p{i}"] = nc
         return x, new_cache
 

@@ -92,7 +92,7 @@ def test_topk_frac_out_of_range_rejected(frac):
 
 
 def test_topk_frac_must_be_static():
-    """A traced frac would make output SHAPES data-dependent — reject it
+    """A traced frac would make output shapes data-dependent — reject it
     eagerly with a clear message instead of a deep jit shape error."""
     t = _tree()
     with pytest.raises(TypeError, match="static"):
